@@ -54,7 +54,7 @@ from repro.engine import jax_ops as J
 from repro.graphs import generators as gen
 from repro.kernels import gs_sweep
 from repro.kernels.gs_sweep import gs_multisweep_pallas
-from repro.kernels.ops import pack_algorithm
+from repro.kernels.ops import interpret_mode, pack_algorithm
 
 REPEATS = 3
 # bs=16 exposes the block-level skew (hub row-blocks vs tail) even on the
@@ -132,7 +132,7 @@ def _batched_round_us(ops, sweeps: int, bs: int) -> float:
     args = (ops["rowptr"], ops["tilecols"], ops["revptr"], ops["revrows"],
             dirty, ops["tiles"], ops["c"], ops["x0"], ops["fixed"])
     kw = dict(semiring=ops["semiring"], combine=ops["combine"], bs=bs,
-              sweeps=sweeps, eps=-1.0)
+              sweeps=sweeps, eps=-1.0, interpret=interpret_mode())
 
     active = np.asarray(gs_multisweep_pallas(*args, ops["x"], **kw)[2])
     assert np.all(active[:, 0] == nb), (

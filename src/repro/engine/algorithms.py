@@ -419,9 +419,16 @@ def _exact_linear_sum(
         x = np.where(fixed, np.asarray(x_fixed, np.float64), x)
     w64 = w.astype(np.float64)
     wv = w64 if c64.ndim == 1 else w64[:, None]
+
+    def scatter_add(msgs: np.ndarray) -> np.ndarray:
+        # bincount accumulates in edge order like np.add.at, ~10x faster
+        if msgs.ndim == 1:
+            return np.bincount(dst, weights=msgs, minlength=n)
+        return np.stack([np.bincount(dst, weights=msgs[:, j], minlength=n)
+                         for j in range(msgs.shape[1])], axis=1)
+
     for _ in range(iters):
-        agg = np.zeros_like(x)
-        np.add.at(agg, dst, x[src] * wv)
+        agg = scatter_add(x[src] * wv)
         x_new = c64 + agg
         if fixed is not None:
             x_new = np.where(fixed, np.asarray(x_fixed, np.float64), x_new)

@@ -72,7 +72,8 @@ class EngineOptions:
     backend : ``"jax"`` (gather/segment-reduce sweeps) or ``"pallas"``
         (fused flat-BSR kernel; ``engine="async_block"`` only).
     bs : block size of the processing order (block engines; ignored by
-        ``engine="sync"``, which runs whole-graph Jacobi rounds).
+        ``engine="sync"``, which runs whole-graph Jacobi rounds). The pallas
+        backend lowered for a TPU takes only multiples of 128.
     inner : per-block refinement sweeps (block engines, jax backend).
     sweeps_per_call : sweeps batched into one persistent megakernel launch
         (pallas backend only; > 1 enables in-kernel convergence and
@@ -215,6 +216,17 @@ def validate_options(
             raise EngineOptionsError(
                 "backend='pallas' runs the fused sweep; inner must be 1"
             )
+        if engine == "async_block":
+            from repro.kernels.ops import LANES, interpret_mode
+
+            if o.bs % LANES and not interpret_mode():
+                # the lowered megakernel DMAs (bs, bs) tiles whose minor
+                # dimension must fill whole lanes; rounding bs up silently
+                # would change the processing blocks the caller asked for
+                raise EngineOptionsError(
+                    f"backend='pallas' lowers for the TPU here, which takes "
+                    f"a bs that is a multiple of {LANES}; got bs={o.bs}"
+                )
     elif engine != "push" and (o.sweeps_per_call != 1 or o.frontier is not None):
         raise EngineOptionsError(
             "sweeps_per_call/frontier amortize kernel launches and DMAs — "

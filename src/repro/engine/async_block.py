@@ -21,7 +21,8 @@ been relabeled with the processing order (``AlgoInstance.relabel``), so block
 b covers ordinals [b*bs, (b+1)*bs).
 
 ``backend="pallas"`` runs sweeps through the fused `kernels.gs_sweep` Pallas
-kernel (ragged flat-BSR tiles; interpret mode off-TPU) instead of the
+kernel (ragged flat-BSR tiles; interpreted on the CPU, see
+`kernels.ops.interpret_mode`) instead of the
 pure-JAX gather/segment-reduce sweep. With ``sweeps_per_call=1`` (default)
 each sweep is its own kernel launch and the per-sweep driver
 (`harness.loop`) keeps the exact per-column freezing semantics; with
@@ -162,8 +163,8 @@ def run_async_block(
     the incremental serving engine's warm starts).
 
     backend: "jax" (gather/segment-reduce sweep) or "pallas" (fused
-    `gs_sweep` kernel per sweep over the ragged flat-BSR layout; interpret
-    mode off-TPU; sum/min/max semirings — see kernels/gs_sweep._SUPPORTED).
+    `gs_sweep` kernel per sweep over the ragged flat-BSR layout; interpreted
+    on the CPU; sum/min/max semirings — see kernels/gs_sweep._SUPPORTED).
 
     extrapolate_every: Aitken acceleration period for linear (sum-semiring)
     systems; 0 = off (see `harness.loop`).
@@ -188,11 +189,11 @@ def run_async_block(
 
 
 def _run_async_block_pallas(
-    algo, bs, max_iters, inner, x_init, interpret=None, extrapolate_every=0,
+    algo, bs, max_iters, inner, x_init, extrapolate_every=0,
     sweeps_per_call=1, frontier=None, tracer=None,
 ) -> RunResult:
     from repro.engine.api import EngineOptions, validate_options
-    from repro.kernels.ops import _auto_interpret, pack_algorithm
+    from repro.kernels.ops import interpret_mode, pack_algorithm
 
     # also reachable through the kernels.ops back-compat shim, which skips
     # solve(); route its options through the same single validation pass
@@ -203,14 +204,15 @@ def _run_async_block_pallas(
     ), algo)
     with tspan(tracer, "pack", algo=algo.name, n=algo.n, d=algo.d, bs=bs):
         ops = pack_algorithm(algo, bs)
-    x_start = harness.init_state(ops["x0_host"], x_init, algo.n)
+    x_start = harness.init_state(ops["x0_host"], x_init, algo.n, d=algo.d)
+    interp = interpret_mode()
     if sweeps_per_call == 1 and frontier is None:
         out = _run_pallas(
             ops["rowptr"], ops["tilecols"], ops["tiles"], ops["c"], ops["x0"],
             ops["fixed"], jnp.asarray(x_start),
             semiring=ops["semiring"], combine=ops["combine"], bs=bs,
             n_real=algo.n, res_kind=algo.residual, eps=algo.eps,
-            max_iters=max_iters, interpret=_auto_interpret(interpret),
+            max_iters=max_iters, interpret=interp,
             extrapolate_every=extrapolate_every,
         )
         return harness.finalize(algo, *out)
@@ -219,7 +221,6 @@ def _run_async_block_pallas(
 
     nb = int(ops["rowptr"].shape[0]) - 1
     dirty0 = jnp.asarray(frontier_blocks(frontier, algo.n, bs))
-    interp = _auto_interpret(interpret)
 
     def batch_fn(x, dirty):
         return gs_multisweep_pallas(
@@ -313,7 +314,7 @@ class AsyncBlockSession:
     def __init__(
         self, algo: AlgoInstance, bs: int = 256, inner: int = 1,
         backend: str = "jax", sweeps_per_call: int = 1,
-        interpret: bool | None = None, mesh=None, axis: str = "data",
+        mesh=None, axis: str = "data",
         trace=None, trace_attrs: dict | None = None,
     ):
         from repro.engine.api import EngineOptions, validate_options
@@ -358,22 +359,27 @@ class AsyncBlockSession:
             self.c = jnp.asarray(self._dist.c)
             self.fixed = jnp.asarray(self._dist.fixed)
         else:
-            from repro.kernels.ops import _auto_interpret, pack_algorithm
+            from repro.kernels.ops import interpret_mode, pack_algorithm
 
             with pack_span:
                 ops = pack_algorithm(algo, bs)
-            self._ops = ops
-            self._interpret = _auto_interpret(interpret)
+            self._interpret = interpret_mode()
             self.nb = int(ops["rowptr"].shape[0]) - 1
-            self.x0 = ops["x0"]
-            self.c = ops["c"]
-            self.fixed = ops["fixed"]
+            # the session owns the per-column operands; _ops keeps only the
+            # graph, so a swap's replaced operands are freed, not kept alive
+            self.x0 = ops.pop("x0")
+            self.c = ops.pop("c")
+            self.fixed = ops.pop("fixed")
+            # the packed start state is already a buffer of its own
+            self.x = ops.pop("x")
+            self._ops = ops
             # cold start: every block dirty (the only safe default; swaps
             # and batches keep the bitmap faithful from here on)
             self.dirty = jnp.ones(self.nb, jnp.int32)
-        # the resident state: a device buffer distinct from x0 (the pallas
-        # kernels donate/alias their state input — x0 must survive swaps)
-        self.x = jnp.array(self.x0, copy=True)
+        if backend != "pallas":
+            # the resident state: a device buffer distinct from x0 (the
+            # engines must never write through to x0, which swaps reuse)
+            self.x = jnp.array(self.x0, copy=True)
         # cumulative per-column accounting across batches; swap_in inverts
         # it for exactly the swapped column (convergence.reinit_columns)
         self.col_done = jnp.zeros(self.d, bool)
@@ -381,12 +387,12 @@ class AsyncBlockSession:
 
     @property
     def state(self):
-        """The resident (n, d) state, padding rows stripped.
+        """The resident (n, d) state, padding rows and lanes stripped.
 
         A device jax array — the serving layer transfers it to host only at
         ticket resolution (`GraphServer._resolve`), never between batches.
         """
-        return self.x[: self.n]
+        return self.x[: self.n, : self.d]
 
     def load_state_column(self, j: int, col) -> None:
         """Overwrite state column ``j`` rows ``< n`` (delta-rebuild carry).
@@ -528,10 +534,11 @@ class AsyncBlockSession:
             rounds, col_done, col_rounds = jax.device_get(
                 (out[1], out[2], out[3])
             )  # repro: allow-host-sync(per-batch convergence report for the caller)
+        # the pallas state's lane-padding columns are not part of the report
         rep = BatchReport(
             rounds=int(rounds),
-            col_done=np.asarray(col_done),
-            col_rounds=np.asarray(col_rounds, np.int32),
+            col_done=np.asarray(col_done)[: self.d],
+            col_rounds=np.asarray(col_rounds, np.int32)[: self.d],
         )
         # fold into the cumulative device-side accounting: columns already
         # done before this batch only re-verified (their 1-round report is

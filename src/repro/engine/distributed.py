@@ -32,9 +32,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-
-from repro.runtime.jax_compat import make_mesh, pvary, set_mesh, shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.engine.algorithms import AlgoInstance
 from repro.engine.convergence import RunResult
@@ -67,9 +65,6 @@ def make_superstep(
         def inner_fn(x_full, esrc, edst, ew, emask, c_blk, fixed_blk, x0_blk):
             dev = jax.lax.axis_index(axis_name)
             d = x_full.shape[1]
-            # the carry becomes device-varying after the first block update;
-            # mark the replicated input as varying up-front
-            x_full = pvary(x_full, (axis_name,))
 
             def block_update(j, x_work):
                 gi = dev * nb_local + j  # global block id
@@ -90,12 +85,14 @@ def make_superstep(
             dev0 = dev * nb_local * bs
             return jax.lax.dynamic_slice(x_work, (dev0, 0), (nb_local * bs, d))
 
-        return shard_map(
+        # check_vma=False: the replicated state becomes device-varying after
+        # the first block update, which the carry does not declare
+        return jax.shard_map(
             inner_fn,
-            mesh,
-            (P(None), P(axis_name), P(axis_name), P(axis_name),
-             P(axis_name), P(axis_name), P(axis_name), P(axis_name)),
-            P(axis_name),
+            mesh=mesh,
+            in_specs=(P(None), P(axis_name), P(axis_name), P(axis_name),
+                      P(axis_name), P(axis_name), P(axis_name), P(axis_name)),
+            out_specs=P(axis_name),
             check_vma=False,
         )(x_full, esrc, edst, ew, emask, c_blk, fixed_blk, x0_blk)
 
@@ -117,13 +114,19 @@ class DistContext:
     def __init__(self, algo: AlgoInstance, bs: int, mesh=None,
                  axis: str = "data", inner: int = 1):
         if mesh is None:
-            mesh = make_mesh((len(jax.devices()),), (axis,))
+            mesh = jax.make_mesh(
+                (len(jax.devices()),), (axis,),
+                axis_types=(jax.sharding.AxisType.Auto,),
+            )
         self.mesh, self.axis, self.bs = mesh, axis, bs
         ndev = mesh.shape[axis]
         be, x0, c, fixed, npad = harness.pack(algo, bs)
         self.nb = ((be.nb + ndev - 1) // ndev) * ndev
         self.npad2 = self.nb * bs
-        self._edges = tuple(jnp.asarray(a) for a in (
+        # each device holds only its own blocks' in-edges, placed where the
+        # superstep's shard_map reads them
+        edge_sharding = NamedSharding(mesh, P(axis))
+        self._edges = tuple(jax.device_put(a, edge_sharding) for a in (
             _pad_blocks(be.esrc, self.nb, 0),
             _pad_blocks(be.edst, self.nb, 0),
             _pad_blocks(be.ew, self.nb, 0.0),
@@ -173,7 +176,7 @@ class DistContext:
     def run(self, x_start, x0, c, fixed, *, max_iters: int,
             extrapolate_every: int = 0):
         """Drive supersteps to convergence; the `harness.loop` tuple."""
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._run(
                 jnp.asarray(x_start), *self._edges, jnp.asarray(x0),
                 jnp.asarray(c), jnp.asarray(fixed), self._real_mask,

@@ -79,27 +79,29 @@ def pack(algo: AlgoInstance, bs: int):
 
 
 def init_state(
-    x0_packed: np.ndarray, x_init, n: int
+    x0_packed: np.ndarray, x_init, n: int, d: Optional[int] = None
 ) -> np.ndarray:
     """Overlay a resume state onto the packed x0 (checkpointed macro-steps).
 
     ``x_init`` may be (n,), (n, 1) or (n, d) — 1-D resumes of a d = 1 run and
-    full-matrix resumes of a batched run both work.
+    full-matrix resumes of a batched run both work. ``d`` is the run's real
+    column count when the packed matrix carries lane padding past it
+    (`kernels.ops.pad_lanes`); the padding columns keep their x0.
     """
     if x_init is None:
         return x0_packed
+    if d is None:
+        d = x0_packed.shape[1]
     x = np.asarray(x_init, dtype=x0_packed.dtype)
     if x.size % n:
         raise ValueError(
             f"x_init has {x.shape} elements, expected (n, d) rows for n={n}"
         )
     x = x.reshape(n, -1)
-    if x.shape[1] != x0_packed.shape[1]:
-        raise ValueError(
-            f"x_init has {x.shape[1]} columns, run has {x0_packed.shape[1]}"
-        )
+    if x.shape[1] != d:
+        raise ValueError(f"x_init has {x.shape[1]} columns, run has {d}")
     out = x0_packed.copy()
-    out[:n, :] = x
+    out[:n, :d] = x
     return out
 
 
@@ -406,6 +408,9 @@ def finalize(
 ) -> RunResult:
     """Convert raw loop outputs into a RunResult (d = 1 keeps 1-D x).
 
+    Padding rows and any lane-padding columns past ``algo.d`` are dropped
+    from the state and from the per-column accounting.
+
     Also attaches the uniform :class:`~repro.obs.telemetry.ConvergenceTrace`
     — derived purely from the residual buffer and ``col_rounds`` fetched by
     this function's single end-of-run readback, so telemetry never adds a
@@ -418,11 +423,11 @@ def finalize(
         (x, k, col_done, col_rounds, res_buf, sum_buf)
     )  # repro: allow-host-sync(end-of-run RunResult readout)
     k = int(k)
-    xr = np.asarray(x)[: algo.n]
+    xr = np.asarray(x)[: algo.n, : algo.d]
     if algo.d == 1:
         xr = xr[:, 0]
-    col_conv = np.asarray(col_done)
-    col_rounds = np.asarray(col_rounds)
+    col_conv = np.asarray(col_done)[: algo.d]
+    col_rounds = np.asarray(col_rounds)[: algo.d]
     residuals = np.asarray(res_buf)[:k]
     return RunResult(
         x=xr,

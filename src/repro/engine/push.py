@@ -83,9 +83,6 @@ _KERNEL_SEMIRING: dict[tuple[str, str], str] = {
 _COMBINES = {"plus_times": "replace", "min_plus": "min_old",
              "max_min": "max_old", "max_times": "max_old"}
 
-# static edge-chunk size for the scatter kernel (hubs loop over chunks)
-_ECAP = 128
-
 
 def _kernel_semiring(algo: AlgoInstance) -> str:
     key = (algo.semiring.reduce, algo.semiring.edge_op)
@@ -207,15 +204,17 @@ def _eps_vec(algo: AlgoInstance, beta: float) -> np.ndarray:
             ** (1.0 - beta)).astype(np.float32)
 
 
-def _make_prep(ks: str) -> Callable[..., tuple[jnp.ndarray, ...]]:
+def _make_prep(ks: str, d: int) -> Callable[..., tuple[jnp.ndarray, ...]]:
     """Jitted per-round prep: pending mask, per-column metrics, the
     bucketing priority key, and the state-sum trace sample — one fused
-    device pass, so the host reads back only what it must."""
+    device pass, so the host reads back only what it must. Reads the first
+    ``d`` columns only: the pallas backend's lane padding is not state."""
     lat_min = ks == "min_plus"
 
     @jax.jit
     def prep(p: jnp.ndarray, r: jnp.ndarray, eps_v: jnp.ndarray,
              col_live: jnp.ndarray) -> tuple[jnp.ndarray, ...]:
+        p, r = p[:, :d], r[:, :d]
         if ks == "plus_times":
             pend = jnp.abs(r) > eps_v[:, None]
             metric = jnp.max(jnp.abs(r), axis=0)
@@ -295,33 +294,50 @@ def _pow2(x: int) -> int:
 
 
 class _PallasRound:
-    """Host-side bucketing + kernel dispatch for one push round."""
+    """Host-side bucketing + kernel dispatch for one push round.
+
+    The kernel moves whole 128-lane rows, so the ``(p, r)`` state it works
+    on is lane-padded (:meth:`pad`). The padding columns are pinned: the
+    post-round cleanup resets them, so they never pend and never reach a
+    real column."""
 
     def __init__(self, algo: AlgoInstance, ks: str, buckets: int) -> None:
+        from repro.kernels.ops import interpret_mode
+        from repro.kernels.push_scatter import pad_edges
+
         indptr, nbrs, eid = Graph(algo.n, algo.src, algo.dst, algo.w).csr()
         self.indptr = indptr.astype(np.int64)
         self.ks = ks
         self.buckets = buckets
-        self.nbrs = jnp.asarray(np.concatenate(
-            [nbrs.astype(np.int32), np.zeros(_ECAP, np.int32)]))
-        self.ew = jnp.asarray(np.concatenate(
-            [np.asarray(algo.w, np.float32)[eid],
-             np.zeros(_ECAP, np.float32)]))
-        self.fixed = jnp.asarray(algo.fixed)
-        self.x0 = jnp.asarray(algo.x0, jnp.float32).reshape(algo.n, algo.d)
+        self.interpret = interpret_mode()
+        self.nbrs = jnp.asarray(pad_edges(nbrs.astype(np.int32)))
+        self.ew = jnp.asarray(pad_edges(np.asarray(algo.w, np.float32)[eid]))
         ident = ACC_IDENTITY[ks]
+        # the inert value of a padding column, in p and in r alike
+        self._fill = 0.0 if ks == "plus_times" else ident
+        self.fixed = jnp.asarray(self.pad(np.asarray(algo.fixed), True))
+        self.x0 = jnp.asarray(self.pad(
+            np.asarray(algo.x0, np.float32).reshape(algo.n, algo.d),
+            self._fill))
 
         @jax.jit
-        def cleanup(p: jnp.ndarray, r: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+        def cleanup(p: jnp.ndarray, r: jnp.ndarray, fixed: jnp.ndarray,
+                    x0: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
             # pinned rows: clamp the state, drop incoming messages (their x0
             # candidate re-seeds only at init); sum discards pinned residual
             if ks == "plus_times":
-                return jnp.where(self.fixed, self.x0, p), \
-                    jnp.where(self.fixed, 0.0, r)
-            return jnp.where(self.fixed, self.x0, p), \
-                jnp.where(self.fixed, jnp.float32(ident), r)
+                return jnp.where(fixed, x0, p), jnp.where(fixed, 0.0, r)
+            return jnp.where(fixed, x0, p), \
+                jnp.where(fixed, jnp.float32(ident), r)
 
         self._cleanup = cleanup
+
+    def pad(self, a: np.ndarray, fill: Any = None) -> np.ndarray:
+        """Lane-pad an (n, d) host matrix; ``fill`` defaults to the inert
+        state value."""
+        from repro.kernels.ops import pad_lanes
+
+        return pad_lanes(a, self._fill if fill is None else fill)
 
     def __call__(
         self, p: jnp.ndarray, r: jnp.ndarray,
@@ -342,9 +358,10 @@ class _PallasRound:
         p2, r2, _, _ = push_scatter_pallas(
             jnp.asarray(vid), jnp.asarray(seg_s), jnp.asarray(seg_l),
             self.nbrs, self.ew, p, r,
-            semiring=self.ks, buckets=buckets, cap=cap, ecap=_ECAP,
+            semiring=self.ks, buckets=buckets, cap=cap,
+            interpret=self.interpret,
         )
-        return self._cleanup(p2, r2)
+        return self._cleanup(p2, r2, self.fixed, self.x0)
 
 
 def _solve(algo: AlgoInstance, o: EngineOptions) -> RunResult:
@@ -374,14 +391,16 @@ def _solve(algo: AlgoInstance, o: EngineOptions) -> RunResult:
         )
         outdeg = np.bincount(algo.src, minlength=n).astype(np.int64)
 
-        p = jnp.asarray(p0)
-        r = jnp.asarray(r0)
-        eps_dev = jnp.asarray(eps_v)
-        prep = _make_prep(ks)
         round_jax = _make_round_jax(algo, ks) if o.backend == "jax" else None
         round_pallas = (
             _PallasRound(algo, ks, o.buckets) if o.backend == "pallas" else None
         )
+        if round_pallas is not None:
+            p0, r0 = round_pallas.pad(p0), round_pallas.pad(r0)
+        p = jnp.asarray(p0)
+        r = jnp.asarray(r0)
+        eps_dev = jnp.asarray(eps_v)
+        prep = _make_prep(ks, d)
 
     col_done = np.zeros(d, bool)
     col_rounds = np.zeros(d, np.int32)
@@ -452,7 +471,7 @@ def _solve(algo: AlgoInstance, o: EngineOptions) -> RunResult:
 
     converged = bool(col_done.all())
     x = np.asarray(jax.device_get(
-        p
+        p[:, :d]
     ), np.float32)  # repro: allow-host-sync(end-of-run RunResult readout)
     if d == 1:
         x = x[:, 0]

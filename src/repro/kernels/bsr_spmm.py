@@ -84,7 +84,7 @@ def bsr_spmm_pallas(
     semiring: str = "plus_times",
     bs: int,
     dj: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     if semiring not in ACC_IDENTITY:
         raise NotImplementedError(

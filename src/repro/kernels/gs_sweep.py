@@ -123,11 +123,17 @@ _SUPPORTED = {
 }
 
 
+# f32 tiles must not take the MXU's one-pass bf16 default on a TPU: the
+# PageRank family converges to eps ~1e-6, far below bf16's 8-bit mantissa
+_DOT_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def _reduce_tile(semiring: str, acc_ref, tile, xs):
     """acc <- acc (reduce) tile (x) xs for one (bs, bs) tile and (bs, d)
     source block."""
     if semiring == "plus_times":
-        acc_ref[...] += jnp.dot(tile, xs, preferred_element_type=acc_ref.dtype)
+        acc_ref[...] += jnp.dot(tile, xs, preferred_element_type=acc_ref.dtype,
+                                precision=_DOT_PRECISION)
     elif semiring == "min_plus":
         part = jnp.min(tile[:, :, None] + xs[None, :, :], axis=1)
         acc_ref[...] = jnp.minimum(acc_ref[...], part)
@@ -319,7 +325,7 @@ def gs_multisweep_pallas(
     bs: int,
     sweeps: int = 1,
     eps: float = -1.0,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Run up to ``sweeps`` Gauss–Seidel sweeps in one persistent kernel.
 
@@ -345,6 +351,11 @@ def gs_multisweep_pallas(
     ``eps`` is the in-kernel early-out threshold (static): once a sweep's
     deltas are all <= eps, the batch's remaining sweeps are predicated
     no-ops. ``eps=-1.0`` disables the early-out (metrics are >= 0).
+
+    ``interpret`` runs the Pallas interpreter instead of lowering for the
+    TPU (`repro.kernels.ops.interpret_mode` decides it for every caller).
+    Lowered, ``d`` must be a multiple of 128 (the lane width of the row-block
+    DMAs) and ``bs`` a multiple of 128 (the tile DMA's minor dimension).
     """
     _check_pair(semiring, combine)
     if sweeps < 1:
@@ -374,11 +385,14 @@ def gs_multisweep_pallas(
             pl.BlockSpec((bs, d), lambda s, i, *_: (i, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
+        # per-sweep rows are 3-D so each block spans the array's last two
+        # dims, the only sub-array block Mosaic takes below (8, 128); the
+        # frontier is written one flag at a time, which only SMEM allows
         out_specs=(
-            pl.BlockSpec(memory_space=pl.ANY),              # x (aliased)
-            pl.BlockSpec((1, d), lambda s, i, *_: (s, 0)),  # deltas
-            pl.BlockSpec((1, 1), lambda s, i, *_: (s, 0)),  # active counts
-            pl.BlockSpec(memory_space=pltpu.VMEM),          # dirty_out
+            pl.BlockSpec(memory_space=pl.ANY),                       # x
+            pl.BlockSpec((None, 1, d), lambda s, i, *_: (s, 0, 0)),  # deltas
+            pl.BlockSpec((None, 1, 1), lambda s, i, *_: (s, 0, 0)),  # active
+            pl.BlockSpec(memory_space=pltpu.SMEM),                   # dirty
         ),
         scratch_shapes=[
             pltpu.VMEM((2, bs, d), x.dtype),   # xblk: double-buffered gathers
@@ -394,19 +408,20 @@ def gs_multisweep_pallas(
             pltpu.SemaphoreType.DMA,           # sem_o (old fetch + writeback)
         ],
     )
-    return pl.pallas_call(
+    x_out, deltas, active, dirty_out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((n, d), x.dtype),
-            jax.ShapeDtypeStruct((sweeps, d), jnp.float32),
-            jax.ShapeDtypeStruct((sweeps, 1), jnp.float32),
+            jax.ShapeDtypeStruct((sweeps, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((sweeps, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((nb,), jnp.int32),
         ),
         # x (after the 5 prefetch args) -> output 0
         input_output_aliases={9: 0},
         interpret=interpret,
     )(rowptr, tilecols, revptr, revrows, dirty, tiles, c, x0, fixed, x)
+    return x_out, deltas[:, 0, :], active[:, 0, :], dirty_out
 
 
 @functools.partial(
@@ -425,7 +440,7 @@ def gs_sweep_pallas(
     semiring: str = "plus_times",
     combine: str = "replace",
     bs: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """One full sweep, state in / state out — the legacy per-sweep entry
     point, now the ``sweeps=1`` megakernel with an all-dirty frontier and the

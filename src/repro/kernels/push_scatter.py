@@ -30,11 +30,14 @@ message on the emptied residual row (the sum invariant ``r = c + Wp - p``
 survives self-loops).
 
 The CSR out-neighbor segment ``nbrs[seg_start[k] : +seg_len[k]]`` is walked
-in chunks of a static ``ecap``: each chunk is one DMA of neighbor ids and
-weights into SMEM scratch (scalar-indexable), then per-edge (1, d) residual
-rows are gather/scatter-DMA'd through VMEM. Hub vertices of any degree cost
-``ceil(deg/ecap)`` chunk DMAs; ``nbrs``/``ew`` must be tail-padded by
-``ecap`` entries so the final static-size chunk DMA cannot overrun.
+in the ``ecap``-aligned chunks it overlaps: each chunk is one DMA of neighbor
+ids and weights into SMEM scratch (scalar-indexable), then per-edge (1, d)
+residual rows are gather/scatter-DMA'd through VMEM. A segment can start
+anywhere, so the chunk start is rounded down to a multiple of ``ecap`` and
+the edge loop skips the slots outside the segment: the TPU tiles a 1-D HBM
+array by 1,024 elements and accepts only a DMA start it can prove aligned.
+``nbrs``/``ew`` are padded to a multiple of ``ecap`` (`pad_edges`); a vertex
+of degree ``deg`` costs at most ``ceil(deg/ecap) + 1`` chunk DMAs.
 
 VMEM per step: four (1, d) rows + two (1, 1) counters; SMEM: the two
 (ecap,) edge buffers — independent of n, m, and d beyond the rows
@@ -46,6 +49,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -116,11 +120,13 @@ def _make_kernel(semiring: str, buckets: int, cap: int, ecap: int):
 
             lo = seg_ref[k]
             deg = len_ref[k]
+            hi = lo + deg
+            first = lo // ecap
 
             def chunk(ci, _):
-                # one static-size DMA per ecap edges (tail padding makes the
-                # overrun slots harmless; the inner bound ignores them)
-                off = lo + ci * ecap
+                # one aligned static-size DMA per chunk the segment overlaps;
+                # the edge loop's bounds drop the slots outside [lo, hi)
+                off = pl.multiple_of((first + ci) * ecap, ecap)
                 cp_n = pltpu.make_async_copy(
                     nbrs_hbm.at[pl.ds(off, ecap)], ebuf, sem_e.at[0]
                 )
@@ -131,7 +137,6 @@ def _make_kernel(semiring: str, buckets: int, cap: int, ecap: int):
                 cp_w.start()
                 cp_n.wait()
                 cp_w.wait()
-                m_here = jnp.minimum(deg - ci * ecap, ecap)
 
                 def edge(t, _):
                     v = ebuf[t]
@@ -158,10 +163,11 @@ def _make_kernel(semiring: str, buckets: int, cap: int, ecap: int):
                     wb_v.wait()
                     return 0
 
-                jax.lax.fori_loop(0, m_here, edge, 0)
+                jax.lax.fori_loop(jnp.maximum(lo - off, 0),
+                                  jnp.minimum(hi - off, ecap), edge, 0)
                 return 0
 
-            nchunks = (deg + ecap - 1) // ecap
+            nchunks = jnp.where(deg > 0, (hi - 1) // ecap - first + 1, 0)
             jax.lax.fori_loop(0, nchunks, chunk, 0)
 
             cnt[...] += 1.0
@@ -173,6 +179,18 @@ def _make_kernel(semiring: str, buckets: int, cap: int, ecap: int):
     return kernel
 
 
+# edges per chunk DMA: the TPU tiles a 1-D 32-bit HBM array by 1,024
+EDGE_CHUNK = 1024
+
+
+def pad_edges(a: np.ndarray, ecap: int = EDGE_CHUNK) -> np.ndarray:
+    """Zero-pad a CSR edge array to a whole number of ``ecap`` chunks (at
+    least one), the length `push_scatter_pallas` requires of ``nbrs``/``ew``."""
+    out = np.zeros(max(1, -(-len(a) // ecap)) * ecap, a.dtype)
+    out[: len(a)] = a
+    return out
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("semiring", "buckets", "cap", "ecap", "interpret"),
@@ -181,16 +199,16 @@ def push_scatter_pallas(
     vid: jnp.ndarray,        # int32[buckets*cap]  vertex per slot, -1 = pad
     seg_start: jnp.ndarray,  # int32[buckets*cap]  CSR out-segment start
     seg_len: jnp.ndarray,    # int32[buckets*cap]  CSR out-segment length
-    nbrs: jnp.ndarray,       # int32[m + ecap]     CSR out-neighbors (padded)
-    ew: jnp.ndarray,         # f32[m + ecap]       edge weights (padded)
+    nbrs: jnp.ndarray,       # int32[m_pad]        CSR out-neighbors (pad_edges)
+    ew: jnp.ndarray,         # f32[m_pad]          edge weights (pad_edges)
     p: jnp.ndarray,          # f32[n, d]           settled state (aliased)
     r: jnp.ndarray,          # f32[n, d]           pending residual (aliased)
     *,
     semiring: str = "plus_times",
     buckets: int,
     cap: int,
-    ecap: int = 128,
-    interpret: bool = True,
+    ecap: int = EDGE_CHUNK,
+    interpret: bool,
 ):
     """One bucketed push round. Returns ``(p, r, pushed, edges)``:
 
@@ -201,6 +219,10 @@ def push_scatter_pallas(
     Slots run in flat ``b * cap + j`` order; the host places the best
     priority bucket first. Padding slots (``vid < 0``) are predicated
     no-ops: zero DMAs, zero messages.
+
+    ``interpret`` runs the Pallas interpreter instead of lowering for the
+    TPU (`repro.kernels.ops.interpret_mode`). Lowered, ``d`` must be a
+    multiple of 128: the (1, d) row DMAs move whole lanes.
     """
     _check_semiring(semiring)
     if buckets < 1 or cap < 1 or ecap < 1:
@@ -211,6 +233,7 @@ def push_scatter_pallas(
     assert vid.shape == (buckets * cap,), (vid.shape, buckets, cap)
     assert seg_start.shape == vid.shape and seg_len.shape == vid.shape
     assert nbrs.shape == ew.shape and nbrs.ndim == 1
+    assert nbrs.shape[0] % ecap == 0, (nbrs.shape, ecap)
     kernel = _make_kernel(semiring, buckets, cap, ecap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -222,10 +245,11 @@ def push_scatter_pallas(
             pl.BlockSpec(memory_space=pl.ANY),  # r (aliased)
         ],
         out_specs=(
-            pl.BlockSpec(memory_space=pl.ANY),              # p (aliased)
-            pl.BlockSpec(memory_space=pl.ANY),              # r (aliased)
-            pl.BlockSpec((1, 1), lambda b, j, *_: (b, 0)),  # pushed/bucket
-            pl.BlockSpec((1, 1), lambda b, j, *_: (b, 0)),  # edges/bucket
+            pl.BlockSpec(memory_space=pl.ANY),  # p (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),  # r (aliased)
+            # 3-D so each (1, 1) block spans the array's last two dims
+            pl.BlockSpec((None, 1, 1), lambda b, j, *_: (b, 0, 0)),  # pushed
+            pl.BlockSpec((None, 1, 1), lambda b, j, *_: (b, 0, 0)),  # edges
         ),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),   # urow: u's settled row
@@ -242,16 +266,17 @@ def push_scatter_pallas(
             pltpu.SemaphoreType.DMA((2,)),     # sem_e (edge chunk pair)
         ],
     )
-    return pl.pallas_call(
+    p_out, r_out, pushed, edges = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((n, d), p.dtype),
             jax.ShapeDtypeStruct((n, d), r.dtype),
-            jax.ShapeDtypeStruct((buckets, 1), jnp.float32),
-            jax.ShapeDtypeStruct((buckets, 1), jnp.float32),
+            jax.ShapeDtypeStruct((buckets, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((buckets, 1, 1), jnp.float32),
         ),
         # p, r (after the 3 prefetch args + nbrs + ew) -> outputs 0, 1
         input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
     )(vid, seg_start, seg_len, nbrs, ew, p, r)
+    return p_out, r_out, pushed[:, 0, :], edges[:, 0, :]

@@ -18,10 +18,11 @@ import json
 import time
 import traceback
 
+import jax
+
 
 from repro.configs import ALL_ARCHS, get_config, get_train_overrides
 from repro.launch.mesh import make_production_mesh
-from repro.runtime.jax_compat import set_mesh as compat_set_mesh
 from repro.launch.shapes import SHAPES, cell_applicable
 from repro.launch.steps import build_cell
 from repro.roofline.analysis import (
@@ -69,7 +70,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     t0 = time.time()
     try:
         cell = build_cell(cfg, shape_name, mesh, rules, tcfg=tcfg)
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = cell.fn.lower(*cell.args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
@@ -77,8 +78,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis()
-            if isinstance(ca, list):  # jax 0.4.x: one dict per program
-                ca = ca[0] if ca else {}
             hlo = compiled.as_text()
         # structural HLO analysis: scan/while bodies scaled by trip counts
         # (XLA's cost_analysis counts each computation once — see hlo_parse)

@@ -16,17 +16,20 @@ from __future__ import annotations
 
 import jax
 
-from repro.runtime.jax_compat import make_mesh
+# the sharding rules place arrays themselves; explicit-typed axes (JAX's
+# make_mesh default) would type every array's sharding instead
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 def make_debug_mesh(n_data: int | None = None, n_model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     n_data = n_data or (n // n_model)
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AUTO,) * 2)

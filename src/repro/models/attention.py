@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import apply_rope, init_linear
-from repro.runtime.jax_compat import shard_map as compat_shard_map
 
 NEG_INF = -1.0e30
 
@@ -261,9 +260,9 @@ def decode_append_attend_seqsharded(
         return out, k_cache, v_cache, slot_positions
 
     ba = batch_axis
-    return compat_shard_map(
+    return jax.shard_map(
         partial_fn,
-        mesh,
+        mesh=mesh,
         in_specs=(P(ba), P(ba), P(ba), P(ba, axis), P(ba, axis), P(ba),
                   P(ba, axis)),
         out_specs=(P(ba), P(ba, axis), P(ba, axis), P(ba, axis)),

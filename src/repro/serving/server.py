@@ -857,6 +857,10 @@ class GraphServer:
         probe_new = remake(probe_old, ten.g)
         occupied = [(j, t, fam.queries[j]) for j, t in fam.occupied()]
         old_state = fam.session.state   # device (n_old, d); read per column
+        # release the old session's packed graph and operands before packing
+        # the new ones: on a large graph two packed copies of one family
+        # need not fit the device at once
+        fam.session = None
         new = self._make_family(fam.key, fam.tenant, probe_new)
         # a pure order swap (delta is None) always carries state: the carry
         # is a bit-exact permutation, so even delta_mode="restart" (which

@@ -33,7 +33,6 @@ from repro.sharding.rules import (
 )
 from repro.train import optim
 from repro.train.grad_compress import compressed_psum_tree
-from repro.runtime.jax_compat import set_mesh as compat_set_mesh, shard_map as compat_shard_map
 
 
 @dataclasses.dataclass
@@ -201,9 +200,9 @@ def make_train_step(
                 )
                 return new_params, new_opt, err, {"loss": loss, **om}
 
-            return compat_shard_map(
+            return jax.shard_map(
                 inner,
-                mesh,
+                mesh=mesh,
                 in_specs=(P(), P(), P(), batch_spec),
                 out_specs=(P(), P(), P(), P()),
                 axis_names=set(dp_axes),
@@ -229,7 +228,7 @@ def init_train_state(model: Model, mesh, shardings, seed: int = 0):
     def _init(key):
         return model.init(key)
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = _init(jax.random.PRNGKey(seed))
         opt_state = jax.jit(
             optim.init_opt_state, out_shardings=shardings["opt"]
@@ -247,7 +246,7 @@ def train_loop(
     if params is None:
         params, opt_state = init_train_state(model, mesh, shardings)
     history = []
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start_step, steps):
             t0 = time.perf_counter()
             batch = dataset(step)

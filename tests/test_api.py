@@ -361,3 +361,38 @@ def test_server_transfer_guard_rejects_bad_value(gw):
 
     with pytest.raises(ValueError, match="transfer_guard"):
         GraphServer(gw, transfer_guard="everything")
+
+
+def test_pallas_block_size_must_fill_lanes_when_lowered(monkeypatch):
+    """Lowered for the TPU, the megakernel's tile DMA needs bs to be a
+    multiple of 128: validate_options refuses any other bs (never rounds it
+    up), while interpret-mode runs keep their small test blocks."""
+    from repro.kernels import ops
+
+    algo = get_algorithm("pagerank", gen.powerlaw_cluster(50, 2, seed=0))
+    validate_options("async_block", EngineOptions(backend="pallas", bs=64), algo)
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    for bs in (16, 64, 200):
+        with pytest.raises(EngineOptionsError, match="multiple of 128"):
+            validate_options(
+                "async_block", EngineOptions(backend="pallas", bs=bs), algo)
+    with pytest.raises(EngineOptionsError, match="multiple of 128"):
+        AsyncBlockSession(algo, bs=64, backend="pallas", sweeps_per_call=2)
+    for bs in (128, 256):
+        validate_options("async_block", EngineOptions(backend="pallas", bs=bs), algo)
+    # the jax backend and the push engine have no tile DMA to align
+    validate_options("async_block", EngineOptions(backend="jax", bs=64), algo)
+    validate_options("push", EngineOptions(backend="pallas", bs=64), algo)
+
+
+def test_interpret_mode_is_decided_by_the_backend(monkeypatch):
+    """One decision for every kernel call: lowered on a TPU, interpreted on
+    the CPU, refused anywhere else — never silently interpreted."""
+    from repro.kernels import ops
+
+    assert ops.interpret_mode() is True  # the tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops.interpret_mode()

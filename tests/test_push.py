@@ -446,3 +446,37 @@ def test_server_push_threshold_validation(graphs):
     _, gw = graphs
     with pytest.raises(ValueError, match="push_threshold"):
         GraphServer(gw, slots=2, push_threshold=1.5)
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_push_pallas_passes_the_interpret_decision(interpret, graphs,
+                                                   monkeypatch):
+    """engine='push', backend='pallas' hands the scatter kernel the one
+    interpret decision (`kernels.ops.interpret_mode`) instead of the
+    kernel's own default, so on a TPU it runs lowered. The False case is
+    stopped at the kernel boundary: the CPU cannot lower it."""
+    from repro.kernels import ops, push_scatter
+
+    class Stop(Exception):
+        pass
+
+    seen = []
+    real = push_scatter.push_scatter_pallas
+
+    def spy(*args, **kw):
+        seen.append(kw["interpret"])
+        if not kw["interpret"]:
+            raise Stop
+        return real(*args, **kw)
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: interpret)
+    monkeypatch.setattr(push_scatter, "push_scatter_pallas", spy)
+    g, gw = graphs
+    algo = _algo("sssp", g, gw)
+    if interpret:
+        r = solve(algo, engine="push", backend="pallas")
+        np.testing.assert_array_equal(r.x, run_async_block(algo, bs=BS).x)
+    else:
+        with pytest.raises(Stop):
+            solve(algo, engine="push", backend="pallas")
+    assert seen and set(seen) == {interpret}

@@ -95,14 +95,14 @@ def test_ckpt_elastic_remesh_subprocess():
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt.manager import CheckpointManager
-from repro.runtime.jax_compat import make_mesh
+from jax.sharding import AxisType
 d = tempfile.mkdtemp()
-mesh1 = make_mesh((4, 2), ('data', 'model'))
+mesh1 = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 x = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8),
                    NamedSharding(mesh1, P('data', 'model')))
 mgr = CheckpointManager(d)
 mgr.save(7, {'w': x})
-mesh2 = make_mesh((2, 4), ('data', 'model'))
+mesh2 = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 template = {'params': {'w': jax.ShapeDtypeStruct((8, 8), np.float32)}}
 shardings = {'params': {'w': NamedSharding(mesh2, P('data', 'model'))}}
 tree, man = mgr.restore(template=template, shardings=shardings)
@@ -185,7 +185,6 @@ from repro.sharding.rules import default_rules
 from repro.train.loop import TrainConfig, make_train_step, init_train_state
 from repro.train import optim
 from repro.data.tokens import TokenDataset, TokenDatasetConfig
-from repro.runtime.jax_compat import set_mesh
 
 cfg = get_reduced('olmo-1b')
 model = build_model(cfg)
@@ -196,7 +195,7 @@ step_fn, shardings = make_train_step(model, mesh, rules, tcfg)
 params, opt_state = init_train_state(model, mesh, shardings)
 ds = TokenDataset(TokenDatasetConfig(vocab=cfg.vocab, seq_len=32, global_batch=8, seed=0, structure=1.0))
 losses = []
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for step in range(40):
         params, opt_state, m = step_fn(params, opt_state, ds(step))
         losses.append(float(m['loss']))
@@ -215,7 +214,6 @@ from repro.launch.mesh import make_debug_mesh
 from repro.sharding.rules import default_rules
 from repro.train.loop import TrainConfig, make_train_step, init_train_state
 from repro.data.tokens import TokenDataset, TokenDatasetConfig
-from repro.runtime.jax_compat import set_mesh
 
 cfg = get_reduced('deepseek-7b')
 model = build_model(cfg)
@@ -228,7 +226,7 @@ for nm in (1, 4):
     tcfg = TrainConfig(microbatches=nm)
     step_fn, sh = make_train_step(model, mesh, rules, tcfg)
     params, opt = init_train_state(model, mesh, sh, seed=0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p, o, m = step_fn(params, opt, batch)
     outs[nm] = (jax.tree.leaves(p)[0], float(m['loss']))
 np.testing.assert_allclose(np.asarray(outs[1][0]), np.asarray(outs[4][0]), atol=2e-5)
@@ -270,16 +268,16 @@ def test_grad_compression_subprocess():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.train.grad_compress import compressed_psum_tree, init_error_tree
-from repro.runtime.jax_compat import make_mesh, shard_map
+from jax.sharding import AxisType
 
-mesh = make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',), axis_types=(AxisType.Auto,))
 g = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)).astype(np.float32))
 
 def f(gl, err):
     mean, err = compressed_psum_tree({'g': gl}, ('data',), {'g': err}, 8)
     return mean['g'], err
 
-fm = jax.jit(shard_map(f, mesh, in_specs=(P('data'), P('data')),
+fm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P('data'), P('data')),
                        out_specs=(P(None), P('data')), check_vma=False))
 err = jnp.zeros((8, 64), jnp.float32)[0:1].repeat(8, 0) * 0
 exact = np.asarray(g).mean(axis=0)

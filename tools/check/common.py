@@ -91,13 +91,16 @@ class ShapeEvalError(Exception):
 def eval_shape_expr(node: ast.AST, env: dict):
     """Evaluate a BlockSpec/scratch shape expression at a budget point.
 
-    Supports the arithmetic subset shapes are written in — constants, env
-    names, + - * // / % **, tuples, unary minus, and min/max calls. Anything
-    else raises :class:`ShapeEvalError` so the checker can report the
-    expression as statically unresolvable instead of guessing.
+    Supports the arithmetic subset shapes are written in — constants (None:
+    a squeezed block dimension, size 1), env names, + - * // / % **, tuples,
+    unary minus, and min/max calls. Anything else raises
+    :class:`ShapeEvalError` so the checker can report the expression as
+    statically unresolvable instead of guessing.
     """
     if isinstance(node, ast.Constant) and isinstance(node.value, (int, float)):
         return node.value
+    if isinstance(node, ast.Constant) and node.value is None:
+        return 1  # a squeezed block dimension holds one element
     if isinstance(node, ast.Name):
         if node.id in env:
             return env[node.id]

@@ -81,6 +81,9 @@ DEVICE_ATTRS = {"state", "col_done", "col_rounds", "dirty"}
 # Array metadata — reading these is free, never a transfer.
 METADATA_ATTRS = {"shape", "ndim", "dtype", "size", "nbytes", "sharding"}
 
+# jax.* calls whose result is host data (device_get is audited separately)
+JAX_HOST_FUNCS = {"device_get", "default_backend"}
+
 _JAX_ROOT_MODULES = ("jax", "jax.numpy", "jax.experimental.pallas",
                      "jax.experimental.pallas.tpu", "jax.lax")
 
@@ -197,7 +200,7 @@ class _Jaxiness:
             chain = attr_chain(node.func)
             root = self._chain_root(chain)
             if root in self.jax_aliases:
-                return not self.is_device_get(node)  # device_get -> host
+                return chain.split(".")[-1] not in JAX_HOST_FUNCS
             if isinstance(node.func, ast.Name):
                 if node.func.id in self.jit_funcs:
                     return True

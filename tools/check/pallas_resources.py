@@ -46,7 +46,7 @@ class Spec:
 
     shape: Optional[ast.AST]        # block-shape expression, None if absent
     index_map: Optional[ast.Lambda]
-    memory_space: Optional[str]     # "ANY" | "VMEM" | None
+    memory_space: Optional[str]     # "ANY" | "VMEM" | "SMEM" | None
     line: int
 
     @property
@@ -308,9 +308,14 @@ def _footprint_at(site: KernelSite, env: dict) -> tuple[int, int]:
             continue
         if spec.windowed:
             vmem += 2 * _bytes_of(spec.shape, env)
-        elif spec.memory_space == "VMEM" and i < len(site.out_shapes) \
+        elif spec.memory_space in ("VMEM", "SMEM") \
+                and i < len(site.out_shapes) \
                 and site.out_shapes[i] is not None:
-            vmem += _bytes_of(site.out_shapes[i], env)  # whole-array output
+            b = _bytes_of(site.out_shapes[i], env)  # whole-array output
+            if spec.memory_space == "VMEM":
+                vmem += b
+            else:
+                smem += b
     return vmem, smem
 
 
