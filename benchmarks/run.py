@@ -29,7 +29,6 @@ MODULES = [
     "serving_bench",          # continuous vs static batching (GraphServer)
     "push_bench",             # vertex-granular push vs block sweeps on deltas
     "reorder_bench",          # online reordering on a sustained delta stream
-    "obs_overhead",           # tracing overhead gate (disabled ~0, enabled <10%)
 ]
 
 

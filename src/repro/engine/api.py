@@ -330,28 +330,10 @@ def solve(
         else:
             engine = "async_block"
     validate_options(engine, o, algo)
-    rank: Optional[np.ndarray] = None
-    if o.rank is not None:
-        # run relabeled: the engines sweep blocks of consecutive ids, so the
-        # order becomes real by renaming vertex v to id rank[v]; the caller's
-        # id-space vectors permute in and the result permutes back out
-        from repro.engine.harness import permute_state
-        from repro.graphs.graph import check_permutation
-
-        rank = np.asarray(o.rank)
-        check_permutation(rank, algo.n)
-        algo = algo.relabel(rank)
-        o = dataclasses.replace(
-            o,
-            rank=None,
-            x_init=None if o.x_init is None
-            else permute_state(np.asarray(o.x_init), rank),
-            frontier=None if o.frontier is None
-            else permute_state(np.asarray(o.frontier), rank),
-        )
     # lazy imports: the engine modules import this module for the error
     # family and the shims, so the dispatch edge must not exist at import time
     from repro.engine import async_block, distributed, push, sync
+    from repro.obs.trace import tspan
 
     impl = {
         "sync": sync._solve,
@@ -359,10 +341,29 @@ def solve(
         "distributed": distributed._solve,
         "push": push._solve,
     }[engine]
-    from repro.obs.trace import tspan
-
+    rank: Optional[np.ndarray] = None
     with tspan(o.trace, "solve", algo=algo.name, engine=engine,
                backend=o.backend, n=algo.n, d=algo.d) as sp:
+        if o.rank is not None:
+            # run relabeled: the engines sweep blocks of consecutive ids, so
+            # the order becomes real by renaming vertex v to id rank[v]; the
+            # caller's id-space vectors permute in and the result permutes
+            # back out
+            from repro.engine.harness import permute_state
+            from repro.graphs.graph import check_permutation
+
+            with tspan(o.trace, "relabel", direction="forward"):
+                rank = np.asarray(o.rank)
+                check_permutation(rank, algo.n)
+                algo = algo.relabel(rank)
+                o = dataclasses.replace(
+                    o,
+                    rank=None,
+                    x_init=None if o.x_init is None
+                    else permute_state(np.asarray(o.x_init), rank),
+                    frontier=None if o.frontier is None
+                    else permute_state(np.asarray(o.frontier), rank),
+                )
         if o.transfer_guard is not None:
             import jax
 
@@ -375,9 +376,10 @@ def solve(
         else:
             res = impl(algo, o)
         sp.set(rounds=res.rounds, converged=bool(res.converged))
-    if rank is not None:
-        x = np.asarray(res.x).reshape(algo.n, -1)[rank]
-        if algo.d == 1:
-            x = x[:, 0]
-        res = dataclasses.replace(res, x=x)
+        if rank is not None:
+            with tspan(o.trace, "relabel", direction="inverse"):
+                x = np.asarray(res.x).reshape(algo.n, -1)[rank]
+                if algo.d == 1:
+                    x = x[:, 0]
+                res = dataclasses.replace(res, x=x)
     return res

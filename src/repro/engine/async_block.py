@@ -46,7 +46,7 @@ from repro.engine.algorithms import AlgoInstance
 from repro.engine.convergence import RunResult
 from repro.engine import harness
 from repro.engine import jax_ops as J
-from repro.obs.trace import tspan
+from repro.obs.trace import NULL_SPAN, tspan
 
 
 @partial(
@@ -130,12 +130,13 @@ def _solve(algo: AlgoInstance, o) -> RunResult:
             tracer=o.trace,
         )
     with tspan(o.trace, "pack", algo=algo.name, n=algo.n, d=algo.d, bs=o.bs):
-        be, x0, c, fixed, npad = harness.pack(algo, o.bs)
+        with tspan(o.trace, "pack_build"):
+            be, x0, c, fixed, npad = harness.pack(algo, o.bs)
     x_start = harness.init_state(x0, o.x_init, algo.n)
+    operands = harness.upload(o.trace, be.esrc, be.edst, be.ew, be.emask,
+                              x_start, x0, c, fixed)
     out = _run(
-        jnp.asarray(be.esrc), jnp.asarray(be.edst), jnp.asarray(be.ew),
-        jnp.asarray(be.emask), jnp.asarray(x_start), jnp.asarray(x0),
-        jnp.asarray(c), jnp.asarray(fixed),
+        *operands,
         bs=o.bs, nb=be.nb, n_real=algo.n,
         sem_reduce=algo.semiring.reduce,
         sem_edge=algo.semiring.edge_op,
@@ -203,7 +204,7 @@ def _run_async_block_pallas(
         frontier=frontier, max_iters=max_iters, trace=tracer,
     ), algo)
     with tspan(tracer, "pack", algo=algo.name, n=algo.n, d=algo.d, bs=bs):
-        ops = pack_algorithm(algo, bs)
+        ops = pack_algorithm(algo, bs, tracer=tracer)
     x_start = harness.init_state(ops["x0_host"], x_init, algo.n, d=algo.d)
     interp = interpret_mode()
     if sweeps_per_call == 1 and frontier is None:
@@ -340,14 +341,12 @@ class AsyncBlockSession:
                           bs=bs, backend=backend, **self.trace_attrs)
         if backend == "jax":
             with pack_span:
-                be, x0, c, fixed, _ = harness.pack(algo, bs)
+                with tspan(trace, "pack_build"):
+                    be, x0, c, fixed, _ = harness.pack(algo, bs)
             self.nb = be.nb
-            self._edges = tuple(
-                jnp.asarray(a) for a in (be.esrc, be.edst, be.ew, be.emask)
-            )
-            self.x0 = jnp.asarray(x0)
-            self.c = jnp.asarray(c)
-            self.fixed = jnp.asarray(fixed)
+            *edges, self.x0, self.c, self.fixed = harness.upload(
+                trace, be.esrc, be.edst, be.ew, be.emask, x0, c, fixed)
+            self._edges = tuple(edges)
         elif backend == "distributed":
             from repro.engine.distributed import DistContext
 
@@ -362,7 +361,7 @@ class AsyncBlockSession:
             from repro.kernels.ops import interpret_mode, pack_algorithm
 
             with pack_span:
-                ops = pack_algorithm(algo, bs)
+                ops = pack_algorithm(algo, bs, tracer=trace)
             self._interpret = interpret_mode()
             self.nb = int(ops["rowptr"].shape[0]) - 1
             # the session owns the per-column operands; _ops keeps only the
@@ -409,8 +408,12 @@ class AsyncBlockSession:
         """Seed column ``j``'s cumulative round count (delta-rebuild carry)."""
         self.col_rounds = self.col_rounds.at[j].set(int(rounds))
 
-    def swap_in(self, j: int, q_x0, q_c, q_fixed) -> None:
-        """Install a new query into column ``j`` (between batches)."""
+    def swap_in(self, j: int, q_x0, q_c, q_fixed, span=NULL_SPAN) -> None:
+        """Install a new query into column ``j`` (between batches).
+
+        On the megakernel path an enabled ``span`` (the server's
+        ``swap_in``) gets the query's ``support_vertices`` and the
+        ``dirty_blocks`` it seeds into the frontier."""
         from repro.engine.convergence import reinit_columns
 
         self.col_done, self.col_rounds = reinit_columns(
@@ -444,6 +447,10 @@ class AsyncBlockSession:
                 self.algo.src, self.algo.dst, support, self.n, depth=1
             )
             self.dirty = or_dirty_blocks(self.dirty, touched, self.n, self.bs)
+            if span is not NULL_SPAN:
+                span.set(support_vertices=int(np.count_nonzero(support)),
+                         dirty_blocks=len(np.unique(
+                             np.flatnonzero(touched) // self.bs)))
 
     def run_batch(self, max_iters: int) -> BatchReport:
         """Advance every column up to ``max_iters`` rounds; converged

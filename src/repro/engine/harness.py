@@ -60,6 +60,13 @@ def check_extrapolation(algo: AlgoInstance, extrapolate_every: int) -> None:
         )
 
 
+def upload(tracer, *arrays: np.ndarray) -> tuple:
+    """Place host operands on the device (``jnp.asarray`` each), inside an
+    ``upload`` span that counts their ``bytes``."""
+    with tspan(tracer, "upload", bytes=int(sum(a.nbytes for a in arrays))):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
 def pack(algo: AlgoInstance, bs: int):
     """Pad the algorithm's (n, d) vertex arrays up to whole blocks of ``bs``.
 

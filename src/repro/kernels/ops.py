@@ -16,12 +16,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.algorithms import AlgoInstance
+from repro.engine import harness
 from repro.engine.convergence import RunResult
 from repro.graphs.blocked import pack_bsr_flat, pad_state, padded_n
 from repro.graphs.graph import Graph
 from repro.kernels.bsr_spmm import bsr_spmm_pallas
 from repro.kernels.gs_sweep import gs_sweep_pallas
 from repro.kernels.semirings import TILE_FILL
+from repro.obs.trace import tspan
 
 # a vector register's lane count: the lowered kernels' row DMAs move whole
 # lanes, so state widths and block sizes are multiples of it on the chip
@@ -105,7 +107,8 @@ _KERNEL_SEMIRING = {
 }
 
 
-def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None) -> dict:
+def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None,
+                   tracer=None) -> dict:
     """Pack an algorithm's graph + vectors into flat-BSR kernel operands.
 
     The state is (n_padded, lane_width(d)). ``d`` defaults to the
@@ -113,7 +116,9 @@ def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None) -> dict:
     per-column vectors); a larger ``d`` broadcasts a scalar
     (``algo.d == 1``) instance across the batch — the kernel-bench path for
     filling TPU lanes with copies. Columns past ``d`` are inert padding
-    (`pad_lanes`); ``ops["d"]`` is the real width.
+    (`pad_lanes`); ``ops["d"]`` is the real width. With a ``tracer`` the
+    host construction is a ``pack_build`` span and the device placement an
+    ``upload`` span.
     """
     key = (algo.semiring.reduce, algo.semiring.edge_op)
     if key not in _KERNEL_SEMIRING:
@@ -122,9 +127,6 @@ def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None) -> dict:
             f"supported: {sorted(_KERNEL_SEMIRING)}"
         )
     semiring = _KERNEL_SEMIRING[key]
-    g = Graph(algo.n, algo.src, algo.dst, algo.w)
-    bsr = pack_bsr_flat(g, bs, fill=TILE_FILL[semiring])
-    npad = padded_n(algo.n, bs)
     d = algo.d if d is None else d
     if d != algo.d and algo.d != 1:
         raise ValueError(f"cannot broadcast a d={algo.d} instance to d={d}")
@@ -136,25 +138,37 @@ def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None) -> dict:
             out = np.repeat(out, d, axis=1)
         return pad_lanes(out, fillv)
 
-    ident = algo.semiring.identity
-    x0pad = padm(algo.x0, ident)
-    revptr, revrows = bsr.reverse_deps()
+    # host construction and device placement alternate, operand by
+    # operand: each transfer is asynchronous and overlaps the host work on
+    # the operands after it (the tiles' with all of the state columns')
+    with tspan(tracer, "pack_build"):
+        g = Graph(algo.n, algo.src, algo.dst, algo.w)
+        bsr = pack_bsr_flat(g, bs, fill=TILE_FILL[semiring])
+        x0pad = padm(algo.x0, algo.semiring.identity)
+        revptr, revrows = bsr.reverse_deps()
+    graph = harness.upload(tracer, bsr.rowptr, bsr.tilecols, bsr.tilerows,
+                           revptr, revrows, bsr.tiles)
+    state = {}
+    for name, make in (
+        ("c", lambda: padm(algo.c, algo.c_pad_fill)),
+        ("x0", lambda: x0pad),
+        ("fixed", lambda: padm(algo.fixed, 1.0)),   # pads pinned
+        ("x", x0pad.copy),               # the start state: a buffer of its own
+    ):
+        with tspan(tracer, "pack_build"):
+            host = make()
+        (state[name],) = harness.upload(tracer, host)
+    with tspan(tracer, "pack_build"):
+        stats = bsr.stats()
     return {
-        "rowptr": jnp.asarray(bsr.rowptr),
-        "tilecols": jnp.asarray(bsr.tilecols),
-        "tilerows": jnp.asarray(bsr.tilerows),
-        "revptr": jnp.asarray(revptr),
-        "revrows": jnp.asarray(revrows),
-        "tiles": jnp.asarray(bsr.tiles),
-        "c": jnp.asarray(padm(algo.c, algo.c_pad_fill)),
-        "x0": jnp.asarray(x0pad),
+        **dict(zip(("rowptr", "tilecols", "tilerows", "revptr", "revrows",
+                    "tiles"), graph)),
+        **state,
         "x0_host": x0pad,  # host copy kept so warm-starts never read back x0
-        "fixed": jnp.asarray(padm(algo.fixed, 1.0)),  # pads pinned
-        "x": jnp.asarray(x0pad.copy()),
         "semiring": semiring,
         "combine": algo.combine,
-        "bsr_stats": bsr.stats(),
-        "npad": npad,
+        "bsr_stats": stats,
+        "npad": padded_n(algo.n, bs),
         "d": d,
     }
 

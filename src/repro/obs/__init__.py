@@ -1,10 +1,14 @@
 """Observability substrate: span tracing, metrics, convergence telemetry.
 
-Three pieces, deliberately dependency-free (stdlib + numpy only) so every
-layer above — engines, kernels, serving — can import them without cycles:
+Three pieces that import only the stdlib and numpy at module load, so
+every layer above — engines, kernels, serving — can import them without
+cycles:
 
 * `repro.obs.trace` — zero-cost-when-disabled context-manager spans with a
-  ring buffer and an optional JSONL sink.
+  ring buffer and an optional JSONL sink. An enabled span also opens a
+  ``jax.profiler.TraceAnnotation`` (``repro.<name>``), so spans share the
+  profiler's clock; ``jax.profiler`` is imported lazily, on the first
+  enabled span.
 * `repro.obs.metrics` — a counters/gauges/histograms registry with
   ``summary()`` (dict) and ``prometheus_text()`` exporters.
 * `repro.obs.telemetry` — the uniform per-round ``ConvergenceTrace``

@@ -465,8 +465,8 @@ class GraphServer:
                 self.stats.record_cache_hit(tenant=tenant, family=algo)
                 if self.trace is not None:
                     self.trace.event(
-                        "resolve", tenant=tenant, algo=algo, rounds=0,
-                        converged=True, from_cache=True,
+                        "resolve", ticket=t.id, tenant=tenant, algo=algo,
+                        rounds=0, converged=True, from_cache=True,
                     )
                 return t
         self.scheduler.push(t)
@@ -479,12 +479,17 @@ class GraphServer:
         every tick gives every tenant with work a batch before any tenant
         gets a second one. Returns the number of family batches executed
         (0 = fully idle)."""
-        if self.transfer_guard is not None:
-            # every device->host edge inside a tick is audited (device_get
-            # + pragma); the guard makes any future unaudited one a fault
-            with jax.transfer_guard_device_to_host(self.transfer_guard):
-                return self._step_inner()
-        return self._step_inner()
+        with tspan(self.trace, "tick") as sp:
+            if self.transfer_guard is not None:
+                # every device->host edge inside a tick is audited
+                # (device_get + pragma); the guard makes any future
+                # unaudited one a fault
+                with jax.transfer_guard_device_to_host(self.transfer_guard):
+                    worked = self._step_inner()
+            else:
+                worked = self._step_inner()
+            sp.set(batches=worked)
+        return worked
 
     def _step_inner(self) -> int:
         keys = list(self._families)
@@ -764,13 +769,15 @@ class GraphServer:
             )
 
     def _install(self, fam: _Family, j: int, t: Ticket, q: AlgoInstance) -> None:
-        x0, c, fixed = q.x0[:, 0], q.c[:, 0], q.fixed[:, 0]
-        order = self._tenant(fam.tenant).order
-        if order is not None:
-            # pack the id-space query into the session's rank space (host
-            # gathers: these (n,) operands are crossing to the device anyway)
-            x0, c, fixed = x0[order], c[order], fixed[order]
-        fam.session.swap_in(j, x0, c, fixed)
+        with tspan(self.trace, "swap_in", ticket=t.id) as sp:
+            x0, c, fixed = q.x0[:, 0], q.c[:, 0], q.fixed[:, 0]
+            order = self._tenant(fam.tenant).order
+            if order is not None:
+                # pack the id-space query into the session's rank space (host
+                # gathers: these (n,) operands are crossing to the device
+                # anyway)
+                x0, c, fixed = x0[order], c[order], fixed[order]
+            fam.session.swap_in(j, x0, c, fixed, span=sp)
         fam.tickets[j] = t
         fam.queries[j] = q
         t.status = "running"
@@ -786,67 +793,75 @@ class GraphServer:
                 t = self.scheduler.pop(fam.key)
                 if t is None:
                     return
-                if fam.probe_cache is not None and fam.probe_cache[0] == t.id:
-                    q = fam.probe_cache[1]   # the family's own probe: built
-                    fam.probe_cache = None   # and compat-checked by identity
-                else:
-                    try:
-                        q = self._build_query(t)
-                        self._check_compat(fam, q, t)
-                    except (ValueError, KeyError, TypeError, IndexError) as e:
-                        self._fail(t, e)
-                        continue
+                with tspan(self.trace, "admit", ticket=t.id):
+                    q = self._admit(fam, t)
+                if q is None:
+                    continue
                 self._install(fam, j, t, q)
                 break
 
+    def _admit(self, fam: _Family, t: Ticket) -> Optional[AlgoInstance]:
+        """The query of a popped ticket, built and checked against the
+        family; None (the ticket failed) where it cannot join."""
+        if fam.probe_cache is not None and fam.probe_cache[0] == t.id:
+            q = fam.probe_cache[1]   # the family's own probe: built
+            fam.probe_cache = None   # and compat-checked by identity
+            return q
+        try:
+            q = self._build_query(t)
+            self._check_compat(fam, q, t)
+        except (ValueError, KeyError, TypeError, IndexError) as e:
+            self._fail(t, e)
+            return None
+        return q
+
     def _resolve(self, fam: _Family, j: int, t: Ticket, converged: bool) -> None:
-        q = fam.queries[j]
-        ten = self._tenant(fam.tenant)
-        # the ONE (n,)-sized device->host transfer of a query's lifecycle
-        x = jax.device_get(
-            fam.session.state[:, j]
-        )  # repro: allow-host-sync(resolved column becomes the ticket result)
-        if ten.rank is not None:
-            x = x[ten.rank]   # rank space -> id space (x_id[v] = x_r[rank[v]])
-        t.result = x
-        if ten.tuner is not None and converged:
-            was_enabled = ten.tuner.enabled
-            ten.tuner.record_resolve(t.rounds)
-            if was_enabled and not ten.tuner.enabled:
-                self.stats.record_reorder_disabled(ten.name)
-        t.converged = converged
-        t.status = "done"
-        t.resolved_at = self.stats.now()
-        t.graph_version = self._tenant(t.tenant).graph_version
-        self.tickets.pop(t.id, None)
-        self.stats.record_resolve(t)
-        if self.trace is not None:
-            self.trace.event(
-                "resolve", tenant=t.tenant, algo=t.algo, rounds=t.rounds,
-                converged=converged, graph_version=t.graph_version,
-            )
-        if self.cache is not None and converged:
-            support = harness.column_support(
-                q.x0[:, 0], q.c[:, 0], q.fixed[:, 0],
-                reduce=q.semiring.reduce, c_fill=q.c_pad_fill, x=x,
-            )
-            blocks = np.unique(np.nonzero(support)[0] // self.bs)
-            self.cache.put(
-                (t.tenant, t.algo, canon(t.params)), x, t.rounds, blocks,
-                t.graph_version,
-                x0_fill=harness.X0_FILL[q.semiring.reduce],
-            )
-        if not converged:
-            # neutralize the slot: a stale non-converged column would keep
-            # every future batch from early-exiting (converged columns are
-            # frozen/fixpoints and cost nothing, so they can stay)
-            n = q.n
-            fam.session.swap_in(
-                j, np.zeros(n, np.float32),
-                np.full(n, q.c_pad_fill, np.float32), np.ones(n, bool),
-            )
-        fam.tickets[j] = None
-        fam.queries[j] = None
+        with tspan(self.trace, "resolve", ticket=t.id, tenant=t.tenant,
+                   algo=t.algo) as sp:
+            q = fam.queries[j]
+            ten = self._tenant(fam.tenant)
+            # the ONE (n,)-sized device->host transfer of a query's lifecycle
+            x = jax.device_get(
+                fam.session.state[:, j]
+            )  # repro: allow-host-sync(resolved column becomes the ticket result)
+            if ten.rank is not None:
+                x = x[ten.rank]   # rank space -> id space (x_id[v] = x_r[rank[v]])
+            t.result = x
+            if ten.tuner is not None and converged:
+                was_enabled = ten.tuner.enabled
+                ten.tuner.record_resolve(t.rounds)
+                if was_enabled and not ten.tuner.enabled:
+                    self.stats.record_reorder_disabled(ten.name)
+            t.converged = converged
+            t.status = "done"
+            t.resolved_at = self.stats.now()
+            t.graph_version = self._tenant(t.tenant).graph_version
+            self.tickets.pop(t.id, None)
+            self.stats.record_resolve(t)
+            if self.cache is not None and converged:
+                support = harness.column_support(
+                    q.x0[:, 0], q.c[:, 0], q.fixed[:, 0],
+                    reduce=q.semiring.reduce, c_fill=q.c_pad_fill, x=x,
+                )
+                blocks = np.unique(np.nonzero(support)[0] // self.bs)
+                self.cache.put(
+                    (t.tenant, t.algo, canon(t.params)), x, t.rounds, blocks,
+                    t.graph_version,
+                    x0_fill=harness.X0_FILL[q.semiring.reduce],
+                )
+            if not converged:
+                # neutralize the slot: a stale non-converged column would keep
+                # every future batch from early-exiting (converged columns are
+                # frozen/fixpoints and cost nothing, so they can stay)
+                n = q.n
+                fam.session.swap_in(
+                    j, np.zeros(n, np.float32),
+                    np.full(n, q.c_pad_fill, np.float32), np.ones(n, bool),
+                )
+            fam.tickets[j] = None
+            fam.queries[j] = None
+            sp.set(rounds=t.rounds, converged=converged,
+                   graph_version=t.graph_version)
 
     def _rebuild_family(
         self, fam: _Family, delta: Optional[GraphDelta] = None,

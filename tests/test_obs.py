@@ -139,6 +139,151 @@ def test_span_attrs_set_mid_span_land_in_record():
     assert rec.duration_s >= 0.0
 
 
+def test_spans_carry_ids_and_parents():
+    sink = io.StringIO()
+    tr = Tracer(jsonl=sink)
+    with tr.span("tick") as tick:
+        with tr.span("admit", ticket=7) as admit:
+            pass
+        tr.event("resolve", ticket=8, from_cache=True)
+        with tr.span("swap_in", ticket=7):
+            pass
+    assert tick.parent is None and admit.parent == tick.id
+    recs = [json.loads(line) for line in sink.getvalue().splitlines()]
+    assert [r["name"] for r in recs] == ["admit", "resolve", "swap_in", "tick"]
+    assert len({r["id"] for r in recs}) == 4
+    assert all(r["parent"] == tick.id for r in recs[:3])
+    assert recs[3]["parent"] is None and recs[1]["duration_s"] == 0.0
+    assert recs[0]["ticket"] == recs[2]["ticket"] == 7
+    # the stack unwinds through an exception too
+    with pytest.raises(RuntimeError):
+        with tr.span("solve"):
+            with tr.span("pack"):
+                raise RuntimeError("boom")
+    with tr.span("batch") as after:
+        pass
+    assert after.parent is None
+
+
+def test_disabled_path_builds_no_annotation(monkeypatch):
+    """Off means off: a disabled tracer and ``tspan(None, ...)`` never
+    reach the profiler; an enabled span opens exactly one annotation."""
+    from repro.obs import trace as trace_mod
+
+    made = []
+
+    class Annotation:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod, "_trace_annotation", lambda: Annotation)
+    off = Tracer(enabled=False)
+    for sp in (tspan(None, "solve"), tspan(off, "solve"), off.span("pack")):
+        assert sp is NULL_SPAN
+        with sp:
+            pass
+    off.event("resolve")
+    Tracer().event("resolve")            # events stay out of the profiler
+    assert made == []
+    with tspan(Tracer(), "solve"):
+        pass
+    assert made == ["repro.solve"]
+
+
+def test_obs_imports_no_jax():
+    """jax is imported on the first enabled span, not by ``repro.obs``."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; from repro.obs import Tracer, tspan\n"
+        "with tspan(None, 'solve'): pass\n"
+        "with Tracer(enabled=False).span('solve'): pass\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "with Tracer().span('solve'): pass\n"
+        "assert 'jax.profiler' in sys.modules\n"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_spans_land_in_profiler_trace_nested(tmp_path):
+    """Under ``jax.profiler.trace`` the enabled spans appear as host events
+    ``repro.<name>`` whose nesting is the one the ``parent`` ids record."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("solve"):
+            with tr.span("relabel"):
+                pass
+            with tr.span("pack"):
+                with tr.span("pack_build"):
+                    np.ones(1000).sum()
+                with tr.span("upload"):
+                    jnp.ones(8).block_until_ready()
+            tr.event("resolve")
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("repro.")]
+    assert sorted(n for n, _, _ in events) == [
+        "repro.pack", "repro.pack_build", "repro.relabel", "repro.solve",
+        "repro.upload"]
+
+    def enclosing(name, s, e):
+        outer = [(s2, n2) for n2, s2, e2 in events
+                 if n2 != name and s2 <= s and e <= e2]
+        return max(outer)[1] if outer else None
+
+    by_id = {sp.id: sp.name for sp in tr.spans}
+    for name, s, e in events:
+        (sp,) = tr.find(name.removeprefix("repro."))
+        want = None if sp.parent is None else "repro." + by_id[sp.parent]
+        assert enclosing(name, s, e) == want, name
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_solve_spans_nest(gw, backend):
+    """``solve`` covers the relabel both ways; ``pack_build`` sits inside
+    ``pack``, and ``upload`` inside it on pallas, right after it on jax."""
+    algo = get_algorithm("sssp", gw, source=3)
+    rank = np.random.default_rng(4).permutation(gw.n)
+    tr = Tracer()
+    on = solve(algo, options=EngineOptions(
+        bs=BS, backend=backend, rank=rank, trace=tr,
+        sweeps_per_call=4 if backend == "pallas" else 1))
+    off = solve(algo, options=EngineOptions(
+        bs=BS, backend=backend, rank=rank,
+        sweeps_per_call=4 if backend == "pallas" else 1))
+    np.testing.assert_array_equal(on.x, off.x)
+    (sv,) = tr.find("solve")
+    (pack,) = tr.find("pack")
+    builds, ups = tr.find("pack_build"), tr.find("upload")
+    relabels = tr.find("relabel")
+    assert [r.attrs["direction"] for r in relabels] == ["forward", "inverse"]
+    assert all(r.parent == sv.id for r in relabels)
+    assert sv.parent is None and pack.parent == sv.id
+    assert builds and all(b.parent == pack.id for b in builds)
+    # pallas alternates build and placement, operand by operand, so each
+    # transfer overlaps the host work on the operands after it
+    assert len(ups) == (5 if backend == "pallas" else 1)
+    assert all(u.parent == (pack.id if backend == "pallas" else sv.id)
+               and u.attrs["bytes"] > 0 for u in ups)
+    assert relabels[0].t_end <= pack.t_start and pack.t_end <= relabels[1].t_start
+
+
 # --------------------------------------------------------------- registry
 
 

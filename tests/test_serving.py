@@ -566,3 +566,55 @@ def test_server_cache_budget_end_to_end():
     # the retained (most recent) entries still serve hits
     t = srv.submit("ppr", {"seeds": [14]})
     assert t.from_cache
+
+
+@pytest.mark.parametrize("backend,sweeps", [("jax", 1), ("pallas", 2)])
+def test_traced_server_spans_per_ticket(backend, sweeps):
+    """A traced server gives every ticket that ran one ``admit``, one
+    ``swap_in`` and one ``resolve`` span, each carrying its ticket id,
+    each inside a ``tick``; a cache hit stays a zero-length ``resolve``
+    event; and the answers are bitwise those of an untraced server."""
+    from repro.obs import Tracer
+
+    kw = dict(slots=2, bs=BS, rounds_per_batch=2, backend=backend,
+              sweeps_per_call=sweeps)
+    queries = [("sssp", {"source": s}) for s in (0, 7, 100)]
+    queries.append(("ppr", {"seeds": [5]}))
+    tr = Tracer()
+    servers = (GraphServer(GW, **kw), GraphServer(GW, trace=tr, **kw))
+    runs = []
+    for srv in servers:
+        ts = [srv.submit(a, p) for a, p in queries]
+        srv.run()
+        ts.append(srv.submit("sssp", {"source": 7}))      # cache hit
+        runs.append(ts)
+    for plain, traced in zip(*runs):
+        assert traced.rounds == plain.rounds
+        np.testing.assert_array_equal(traced.result, plain.result)
+    assert runs[1][-1].from_cache
+
+    by_id = {sp.id: sp for sp in tr.spans}
+
+    def in_tick(sp):
+        while sp.parent is not None:
+            sp = by_id[sp.parent]
+            if sp.name == "tick":
+                return True
+        return False
+
+    for t in runs[1][:-1]:
+        for name in ("admit", "swap_in", "resolve"):
+            (sp,) = [s for s in tr.find(name) if s.attrs["ticket"] == t.id]
+            assert sp.t_end > sp.t_start and in_tick(sp), (name, t.id)
+        (res,) = [s for s in tr.find("resolve") if s.attrs["ticket"] == t.id]
+        assert res.attrs["rounds"] == t.rounds and res.attrs["converged"]
+        (swap,) = [s for s in tr.find("swap_in") if s.attrs["ticket"] == t.id]
+        if backend == "pallas":
+            assert swap.attrs["support_vertices"] >= 1
+            assert swap.attrs["dirty_blocks"] >= 1
+    hit = runs[1][-1]
+    (ev,) = [s for s in tr.find("resolve") if s.attrs["ticket"] == hit.id]
+    assert ev.duration_s == 0.0 and ev.attrs["from_cache"] and ev.parent is None
+    ticks = tr.find("tick")
+    assert ticks and all(sp.parent is None for sp in ticks)
+    assert sum(sp.attrs["batches"] for sp in ticks) == servers[1].stats.batches
