@@ -205,12 +205,13 @@ def _run_async_block_pallas(
     ), algo)
     with tspan(tracer, "pack", algo=algo.name, n=algo.n, d=algo.d, bs=bs):
         ops = pack_algorithm(algo, bs, tracer=tracer)
-    x_start = harness.init_state(ops["x0_host"], x_init, algo.n, d=algo.d)
+    x_start = harness.init_state_device(ops["x"], ops["x0"], x_init, algo.n,
+                                        algo.d)
     interp = interpret_mode()
     if sweeps_per_call == 1 and frontier is None:
         out = _run_pallas(
             ops["rowptr"], ops["tilecols"], ops["tiles"], ops["c"], ops["x0"],
-            ops["fixed"], jnp.asarray(x_start),
+            ops["fixed"], x_start,
             semiring=ops["semiring"], combine=ops["combine"], bs=bs,
             n_real=algo.n, res_kind=algo.residual, eps=algo.eps,
             max_iters=max_iters, interpret=interp,
@@ -234,7 +235,7 @@ def _run_async_block_pallas(
 
     real_mask = np.arange(x_start.shape[0]) < algo.n
     out = harness.sweep_batched_loop(
-        batch_fn, jnp.asarray(x_start), dirty0,
+        batch_fn, x_start, dirty0,
         eps=algo.eps, max_iters=max_iters, sweeps=sweeps_per_call, nb=nb,
         real_mask=real_mask, tracer=tracer,
     )

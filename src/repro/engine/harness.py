@@ -85,6 +85,20 @@ def pack(algo: AlgoInstance, bs: int):
     return be, x0, c, fixed, npad
 
 
+def _resume_rows(x_init, n: int, d: int, dtype) -> np.ndarray:
+    """``x_init`` as the (n, d) rows a resume overlays: (n,), (n, 1) and
+    (n, d) forms are accepted."""
+    x = np.asarray(x_init, dtype=dtype)
+    if x.size % n:
+        raise ValueError(
+            f"x_init has {x.shape} elements, expected (n, d) rows for n={n}"
+        )
+    x = x.reshape(n, -1)
+    if x.shape[1] != d:
+        raise ValueError(f"x_init has {x.shape[1]} columns, run has {d}")
+    return x
+
+
 def init_state(
     x0_packed: np.ndarray, x_init, n: int, d: Optional[int] = None
 ) -> np.ndarray:
@@ -99,17 +113,19 @@ def init_state(
         return x0_packed
     if d is None:
         d = x0_packed.shape[1]
-    x = np.asarray(x_init, dtype=x0_packed.dtype)
-    if x.size % n:
-        raise ValueError(
-            f"x_init has {x.shape} elements, expected (n, d) rows for n={n}"
-        )
-    x = x.reshape(n, -1)
-    if x.shape[1] != d:
-        raise ValueError(f"x_init has {x.shape[1]} columns, run has {d}")
     out = x0_packed.copy()
-    out[:n, :d] = x
+    out[:n, :d] = _resume_rows(x_init, n, d, x0_packed.dtype)
     return out
+
+
+def init_state_device(x, x0, x_init, n: int, d: int):
+    """:func:`init_state` for device-resident packed operands: a cold start
+    (``x_init`` None) is the packed start state ``x`` as it is; a resume
+    overlays ``x_init``'s rows onto a copy of ``x0`` on the device, so only
+    the (n, d) resume rows transfer."""
+    if x_init is None:
+        return x
+    return x0.at[:n, :d].set(_resume_rows(x_init, n, d, np.float32))
 
 
 def swap_in_column(

@@ -15,7 +15,11 @@ here:
   scalar-prefetched ``rowptr[nb+1]`` / ``tilecols[nnz_blocks]``). Memory, DMA
   count, and semiring FLOPs are all ``O(nnz_blocks)`` — the hub row-blocks
   that GoGraph's HD phase concentrates (paper §IV-A) are paid for once, in
-  their own row, not replicated into every row's padding.
+  their own row, not replicated into every row's padding. The engines never
+  build its tiles on the host: `tile_skeleton` gives the tile structure and
+  each arc's position, and `kernels.ops.pack_algorithm` scatters the weights
+  into the tiles on the device. `pack_bsr_flat` is the host packer, kept as
+  the reference.
 
 * :class:`BSRMatrix` — the legacy *dense-padded* BSR layout
   (``tiles[nb, k_max, bs, bs]``), kept as the comparison baseline: every
@@ -95,22 +99,16 @@ def pack_in_edges(g: Graph, bs: int) -> BlockedInEdges:
 
 
 @dataclasses.dataclass
-class FlatBSRMatrix:
-    """Ragged flat BSR of the in-adjacency: CSR over (bs, bs) tiles.
+class TileStructure:
+    """The nonzero tiles of the in-adjacency, without their payloads: a CSR
+    over (bs, bs) tiles. For destination block i, tiles
+    ``rowptr[i]..rowptr[i+1]`` are its nonzero column-blocks in ascending
+    column order; ``tilerows`` is derived (``repeat`` of the rowptr runs)
+    and carried so `bsr_spmm` can map grid step -> output block without a
+    search. O(nnz_blocks) memory: what `tile_skeleton` builds on the host
+    while the tiles themselves are built on the device.
 
-    For destination block i, tiles ``rowptr[i]..rowptr[i+1]`` hold its
-    nonzero column-blocks in ascending column order:
-
-        y_blk[i] = REDUCE_{t in [rowptr[i], rowptr[i+1])} tiles[t] (x) x_blk[tilecols[t]]
-
-    ``tiles[t]`` has layout (dst_local, src_local). Absent edges *inside* a
-    nonzero tile carry ``fill`` — the semiring's absorbing element (0 for
-    plus_times, +BIG for min_plus, -BIG for max_min) — but there are no
-    padding *tiles*: memory and per-sweep DMAs are O(nnz_blocks), not
-    O(nb * k_max). ``tilerows`` is derived (``repeat`` of the rowptr runs) and
-    carried so `bsr_spmm` can map grid step -> output block without a search.
-
-    Empty graphs keep one never-referenced zero tile (``rowptr`` all zero) so
+    Empty graphs keep one never-referenced entry (``rowptr`` all zero) so
     downstream device buffers are never zero-sized; ``nnz_blocks`` reads the
     real count from ``rowptr[-1]``.
     """
@@ -120,8 +118,6 @@ class FlatBSRMatrix:
     rowptr: np.ndarray    # int32[nb + 1]
     tilecols: np.ndarray  # int32[max(nnz_blocks, 1)]
     tilerows: np.ndarray  # int32[max(nnz_blocks, 1)]  (derived)
-    tiles: np.ndarray     # float32[max(nnz_blocks, 1), bs, bs]
-    fill: float
 
     @property
     def nb(self) -> int:
@@ -171,6 +167,25 @@ class FlatBSRMatrix:
             "tile_bytes_saved": dense_tile_bytes - tile_bytes,
             "padding_waste": 1.0 - nnz / max(1, self.nb * k_max),
         }
+
+
+@dataclasses.dataclass
+class FlatBSRMatrix(TileStructure):
+    """Ragged flat BSR of the in-adjacency: a `TileStructure` with its
+    dense tiles.
+
+        y_blk[i] = REDUCE_{t in [rowptr[i], rowptr[i+1])} tiles[t] (x) x_blk[tilecols[t]]
+
+    ``tiles[t]`` has layout (dst_local, src_local). Absent edges *inside* a
+    nonzero tile carry ``fill`` — the semiring's absorbing element (0 for
+    plus_times, +BIG for min_plus, -BIG for max_min) — but there are no
+    padding *tiles*: memory and per-sweep DMAs are O(nnz_blocks), not
+    O(nb * k_max). The empty graph's one never-referenced tile is all
+    ``fill``.
+    """
+
+    tiles: np.ndarray     # float32[max(nnz_blocks, 1), bs, bs]
+    fill: float
 
 
 @dataclasses.dataclass
@@ -279,6 +294,56 @@ def pack_bsr_flat(g: Graph, bs: int, fill: float = 0.0) -> FlatBSRMatrix:
         bs=bs, n=g.n, rowptr=rowptr.astype(np.int32), tilecols=tilecols,
         tilerows=tilerows, tiles=tiles, fill=fill,
     )
+
+
+def tile_skeleton(
+    g: Graph, bs: int
+) -> tuple[TileStructure, np.ndarray, np.ndarray, np.ndarray]:
+    """The host half of `pack_bsr_flat`, from one sort: the tile structure
+    and, per arc that lands in the tiles, its ``(tile, offset)`` position —
+    ``tiles[tile].ravel()[offset] = w`` rebuilds ``pack_bsr_flat``'s tiles
+    (`kernels.ops` does that scatter on the device).
+
+    One int64 key per arc, ``((dst//bs)*nb + src//bs) * bs*bs + (dst%bs)*bs
+    + src%bs`` (below ``npad**2``), sorted stably, is tile-major, so the
+    tile boundaries give the structure. Repeated ``(dst, src)`` arcs are
+    adjacent and in input order; the last of each run is kept, which is
+    `pack_bsr_flat`'s last-write-wins, and leaves the positions unique and
+    ascending. Returns ``(structure, tile int32, offset int32, w float32)``.
+    """
+    nb = num_blocks(g.n, bs)
+    bb = bs * bs
+    bi, ri = np.divmod(g.dst, bs)
+    bk, ci = np.divmod(g.src, bs)
+    key = bi.astype(np.int64)
+    key *= nb
+    key += bk
+    key *= bb
+    ri *= bs
+    ri += ci
+    key += ri
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    last = np.ones(len(key), bool)
+    last[:-1] = key[1:] != key[:-1]
+    key = key[last]
+    w = g.weights[order[last]].astype(np.float32)
+    tkey = key // bb
+    first = np.ones(len(tkey), bool)
+    first[1:] = tkey[1:] != tkey[:-1]
+    tile = np.cumsum(first, dtype=np.int32)
+    tile -= 1
+    utk = tkey[first]
+    rows = (utk // nb).astype(np.int32)
+    rowptr = np.zeros(nb + 1, dtype=np.int64)
+    np.cumsum(np.bincount(rows, minlength=nb), out=rowptr[1:])
+    nnz = len(utk)
+    structure = TileStructure(
+        bs=bs, n=g.n, rowptr=rowptr.astype(np.int32),
+        tilecols=(utk % nb).astype(np.int32) if nnz else np.zeros(1, np.int32),
+        tilerows=rows if nnz else np.zeros(1, np.int32),
+    )
+    return structure, tile, (key % bb).astype(np.int32), w
 
 
 def block_reverse_deps(

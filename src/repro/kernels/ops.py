@@ -6,10 +6,13 @@ passes its answer through. The wrappers also provide `pack_algorithm`, which
 turns an `AlgoInstance` (with its transformed edge weights) into kernel-ready
 **ragged flat BSR** operands (`graphs.blocked.FlatBSRMatrix`:
 tiles[nnz_blocks, bs, bs] + rowptr + tilecols) with lane-padded state
-columns, and `run_async_block_pallas`, a full async engine whose per-sweep
-work is the fused gs_sweep kernel.
+columns, built on the device from the edge list, and
+`run_async_block_pallas`, a full async engine whose per-sweep work is the
+fused gs_sweep kernel.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +21,7 @@ import numpy as np
 from repro.engine.algorithms import AlgoInstance
 from repro.engine import harness
 from repro.engine.convergence import RunResult
-from repro.graphs.blocked import pack_bsr_flat, pad_state, padded_n
+from repro.graphs.blocked import padded_n, tile_skeleton
 from repro.graphs.graph import Graph
 from repro.kernels.bsr_spmm import bsr_spmm_pallas
 from repro.kernels.gs_sweep import gs_sweep_pallas
@@ -107,18 +110,48 @@ _KERNEL_SEMIRING = {
 }
 
 
+@functools.partial(jax.jit, static_argnames=("nnz", "bs", "npad", "d"))
+def _build_operands(tile, off, w, c, x0, fixed, fill, c_fill, ident, *,
+                    nnz, bs, npad, d):
+    """The megakernel's operands, built on the device from the compact
+    inputs `pack_algorithm` uploads: ``tiles[nnz, bs, bs]`` holds ``fill``
+    with each arc's weight scattered to its ``(tile, offset)`` (unique and
+    ascending, `graphs.blocked.tile_skeleton`), and each real ``(n, d0)``
+    state matrix becomes ``(npad, lane_width(d))``. Rows past ``n`` and
+    columns past ``d`` carry the fills `pad_state` and `pad_lanes` use; a
+    single column (``d0 == 1 < d``) is repeated across the ``d`` columns.
+    ``x``, the start state, is a copy of ``x0`` in a buffer of its own."""
+    tiles = jnp.full((nnz, bs * bs), fill, jnp.float32).at[tile, off].set(
+        w, indices_are_sorted=True, unique_indices=True)
+
+    def pad(a, v):
+        a = jnp.broadcast_to(a, (a.shape[0], d))
+        out = jnp.full((npad, lane_width(d)), v, jnp.float32)
+        return out.at[: a.shape[0], :d].set(a)
+
+    x0 = pad(x0, ident)
+    fixed = pad(fixed, 1.0)   # padding rows and lanes pinned
+    return (tiles.reshape(nnz, bs, bs), pad(c, c_fill), x0, fixed,
+            jnp.copy(x0))
+
+
 def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None,
                    tracer=None) -> dict:
     """Pack an algorithm's graph + vectors into flat-BSR kernel operands.
+
+    The host builds only the O(m + n·d) skeleton (`tile_skeleton`: one sort
+    of the arcs) and uploads it with the real state matrices; the tiles and
+    the lane-padded state are built on the device (`_build_operands`).
 
     The state is (n_padded, lane_width(d)). ``d`` defaults to the
     algorithm's own batch width ``algo.d`` (batched constructors carry real
     per-column vectors); a larger ``d`` broadcasts a scalar
     (``algo.d == 1``) instance across the batch — the kernel-bench path for
     filling TPU lanes with copies. Columns past ``d`` are inert padding
-    (`pad_lanes`); ``ops["d"]`` is the real width. With a ``tracer`` the
-    host construction is a ``pack_build`` span and the device placement an
-    ``upload`` span.
+    (the fills of `pad_lanes`); ``ops["d"]`` is the real width. With a
+    ``tracer`` the host skeleton is a ``pack_build`` span, the one transfer
+    an ``upload`` span and the device build's dispatch a ``pack_device``
+    span.
     """
     key = (algo.semiring.reduce, algo.semiring.edge_op)
     if key not in _KERNEL_SEMIRING:
@@ -131,44 +164,30 @@ def pack_algorithm(algo: AlgoInstance, bs: int, d: int | None = None,
     if d != algo.d and algo.d != 1:
         raise ValueError(f"cannot broadcast a d={algo.d} instance to d={d}")
 
-    # same padding primitive + fill rules as engine.harness.pack
-    def padm(a, fillv):
-        out = pad_state(np.asarray(a, np.float32), bs, fill=fillv)
-        if d != algo.d:
-            out = np.repeat(out, d, axis=1)
-        return pad_lanes(out, fillv)
-
-    # host construction and device placement alternate, operand by
-    # operand: each transfer is asynchronous and overlaps the host work on
-    # the operands after it (the tiles' with all of the state columns')
     with tspan(tracer, "pack_build"):
-        g = Graph(algo.n, algo.src, algo.dst, algo.w)
-        bsr = pack_bsr_flat(g, bs, fill=TILE_FILL[semiring])
-        x0pad = padm(algo.x0, algo.semiring.identity)
-        revptr, revrows = bsr.reverse_deps()
-    graph = harness.upload(tracer, bsr.rowptr, bsr.tilecols, bsr.tilerows,
-                           revptr, revrows, bsr.tiles)
-    state = {}
-    for name, make in (
-        ("c", lambda: padm(algo.c, algo.c_pad_fill)),
-        ("x0", lambda: x0pad),
-        ("fixed", lambda: padm(algo.fixed, 1.0)),   # pads pinned
-        ("x", x0pad.copy),               # the start state: a buffer of its own
-    ):
-        with tspan(tracer, "pack_build"):
-            host = make()
-        (state[name],) = harness.upload(tracer, host)
-    with tspan(tracer, "pack_build"):
-        stats = bsr.stats()
+        skel, tile, off, w = tile_skeleton(
+            Graph(algo.n, algo.src, algo.dst, algo.w), bs)
+        revptr, revrows = skel.reverse_deps()
+        stats = skel.stats()
+        state = [np.asarray(a, np.float32) for a in (algo.c, algo.x0, algo.fixed)]
+    (rowptr, tilecols, tilerows, revptr, revrows, tile, off, w,
+     *state) = harness.upload(tracer, skel.rowptr, skel.tilecols,
+                              skel.tilerows, revptr, revrows, tile, off, w,
+                              *state)
+    npad = padded_n(algo.n, bs)
+    with tspan(tracer, "pack_device"):
+        tiles, c, x0, fixed, x = _build_operands(
+            tile, off, w, *state, TILE_FILL[semiring], algo.c_pad_fill,
+            algo.semiring.identity,
+            nnz=int(skel.tilecols.shape[0]), bs=bs, npad=npad, d=d)
     return {
-        **dict(zip(("rowptr", "tilecols", "tilerows", "revptr", "revrows",
-                    "tiles"), graph)),
-        **state,
-        "x0_host": x0pad,  # host copy kept so warm-starts never read back x0
+        "rowptr": rowptr, "tilecols": tilecols, "tilerows": tilerows,
+        "revptr": revptr, "revrows": revrows, "tiles": tiles,
+        "c": c, "x0": x0, "fixed": fixed, "x": x,
         "semiring": semiring,
         "combine": algo.combine,
         "bsr_stats": stats,
-        "npad": padded_n(algo.n, bs),
+        "npad": npad,
         "d": d,
     }
 

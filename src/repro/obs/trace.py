@@ -11,10 +11,13 @@ already surfaces, never anything per-round on device:
                   "forward"``) or the gather of the answer back to vertex
                   ids (``"inverse"``), inside ``solve``
 ``pack``          operand packing (block padding / flat-BSR layout)
-``pack_build``    host construction of the packed operands, inside ``pack``
+``pack_build``    host construction of the packed operands (on the pallas
+                  backend the tile skeleton), inside ``pack``
 ``upload``        device placement of the packed operands (``bytes``);
                   inside ``pack`` on the pallas backend, right after it on
                   the jax backend
+``pack_device``   dispatch of the pallas backend's device build of the tiles
+                  and padded state from what ``upload`` sent, inside ``pack``
 ``batch``         one bounded-round session batch (`AsyncBlockSession`)
 ``sweep_call``    one megakernel dispatch inside a batch (dispatch-side
                   duration: the launch is asynchronous, the following
@@ -82,7 +85,8 @@ from types import TracebackType
 from typing import IO, Any, Optional, Union
 
 SPAN_NAMES = (
-    "solve", "relabel", "pack", "pack_build", "upload", "batch", "sweep_call",
+    "solve", "relabel", "pack", "pack_build", "upload", "pack_device",
+    "batch", "sweep_call",
     "tick", "admit", "swap_in", "resolve", "delta_apply", "reorder_swap",
 )
 

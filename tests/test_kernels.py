@@ -282,6 +282,133 @@ def test_warm_start_contract_all_pairs(algo_name, _w):
     np.testing.assert_array_equal(r_resume.x, r_cold.x)
 
 
+# ---------------------------------------------------------------------------
+# operands built on the device from the edge list: bitwise the host packers'
+# ---------------------------------------------------------------------------
+
+def _host_operands(algo, bs, d):
+    """What the host packers give: `pack_bsr_flat`'s structure and tiles,
+    its reverse deps, and each state matrix padded by `pad_state` (rows)
+    then `pad_lanes` (columns), a single column repeated to ``d``."""
+    from repro.graphs.blocked import pack_bsr_flat, pad_state
+    from repro.graphs.graph import Graph
+    from repro.kernels.ops import _KERNEL_SEMIRING, pad_lanes
+    from repro.kernels.semirings import TILE_FILL
+
+    semiring = _KERNEL_SEMIRING[(algo.semiring.reduce, algo.semiring.edge_op)]
+    bsr = pack_bsr_flat(Graph(algo.n, algo.src, algo.dst, algo.w), bs,
+                        fill=TILE_FILL[semiring])
+
+    def padm(a, fill):
+        out = pad_state(np.asarray(a, np.float32), bs, fill=fill)
+        if d != algo.d:
+            out = np.repeat(out, d, axis=1)
+        return pad_lanes(out, fill)
+
+    revptr, revrows = bsr.reverse_deps()
+    x0 = padm(algo.x0, algo.semiring.identity)
+    return {"rowptr": bsr.rowptr, "tilecols": bsr.tilecols,
+            "tilerows": bsr.tilerows, "revptr": revptr, "revrows": revrows,
+            "tiles": bsr.tiles, "c": padm(algo.c, algo.c_pad_fill),
+            "x0": x0, "fixed": padm(algo.fixed, 1.0), "x": x0}
+
+
+def _assert_operands_bitwise(ops, ref):
+    for k, want in ref.items():
+        got = np.asarray(ops[k])
+        assert got.dtype == want.dtype and got.shape == want.shape, k
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8),
+                                      err_msg=k)
+    # the start state is a buffer of its own, never an alias of x0
+    assert (ops["x"].unsafe_buffer_pointer()
+            != ops["x0"].unsafe_buffer_pointer())
+
+
+def _edge_case_algo(algo_name, graph):
+    """A d = 1 instance on n = 311 whose arcs repeat with other weights, in
+    shuffled order (the last of each repeated arc wins), or that has none."""
+    import dataclasses
+
+    algo = _contract_algo(algo_name, 1)
+    if graph == "empty":
+        return dataclasses.replace(
+            algo, src=np.zeros(0, np.int32), dst=np.zeros(0, np.int32),
+            w=np.zeros(0, np.float32), exact_fn=None)
+    rng = np.random.default_rng(11)
+    rep = rng.choice(algo.m, size=algo.m // 3, replace=False)
+    src = np.concatenate([algo.src, algo.src[rep], algo.src[rep]])
+    dst = np.concatenate([algo.dst, algo.dst[rep], algo.dst[rep]])
+    w = np.concatenate([algo.w, rng.random(2 * len(rep))]).astype(np.float32)
+    perm = rng.permutation(len(src))
+    return dataclasses.replace(algo, src=src[perm], dst=dst[perm], w=w[perm],
+                               exact_fn=None)
+
+
+@pytest.mark.parametrize("algo_name,_w", PAIRS)
+@pytest.mark.parametrize("case", ["d1", "batched", "broadcast",
+                                  "repeated_arcs", "empty"])
+def test_device_build_matches_host_packers(algo_name, _w, case):
+    """Every operand `pack_algorithm` builds on the device from the edge
+    list equals the host packers' bitwise, for all four kernel semirings
+    (tile fills 0 and +-BIG), on n = 311 (not a multiple of bs = 64): d = 1,
+    a batched d = 3, a d = 1 instance broadcast to d = 8, repeated arcs of
+    different weights, and the empty graph with its one unreferenced tile."""
+    if case in ("repeated_arcs", "empty"):
+        algo = _edge_case_algo(algo_name, case)
+    else:
+        algo = _contract_algo(algo_name, 3 if case == "batched" else 1)
+    d = 8 if case == "broadcast" else algo.d
+    ops = pack_algorithm(algo, bs=64, d=d)
+    assert ops["d"] == d and ops["npad"] == 320
+    _assert_operands_bitwise(ops, _host_operands(algo, 64, d))
+    if case == "empty":
+        assert ops["bsr_stats"]["nnz_blocks"] == 0
+        assert ops["tiles"].shape == (1, 64, 64)
+
+
+def test_pack_uploads_the_skeleton_not_the_tiles():
+    """On a d = 1 PageRank instance the pallas pack sends what the device
+    build needs, one transfer below a tenth of the tiles it builds."""
+    from repro.obs import Tracer
+
+    algo = _contract_algo("pagerank", 1)
+    tr = Tracer()
+    ops = pack_algorithm(algo, bs=64, tracer=tr)
+    (up,) = tr.find("upload")
+    assert 0 < up.attrs["bytes"] < ops["tiles"].nbytes / 10
+
+
+@pytest.mark.parametrize("algo_name,_w", PAIRS)
+@pytest.mark.parametrize("d", [1, 3])
+def test_warm_start_device_overlay(algo_name, _w, d):
+    """x_init overlaid on the device equals the host overlay of the host
+    packers' x0 bitwise, in each accepted shape, and the pallas run from it
+    gives the RunResult.x of the jax engine, which overlays on the host."""
+    from repro.engine import harness
+
+    algo = _contract_algo(algo_name, d)
+    ops = pack_algorithm(algo, bs=64)
+    x0_host = _host_operands(algo, 64, d)["x0"]
+    mid = np.asarray(run_async_block(algo, bs=64, max_iters=3).x)
+    for x_init in (mid, mid.reshape(algo.n, d)):   # (n,) or (n, d); (n, 1)
+        got = harness.init_state_device(ops["x"], ops["x0"], x_init, algo.n, d)
+        want = harness.init_state(x0_host, x_init, algo.n, d)
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                      want.view(np.uint8))
+    assert harness.init_state_device(ops["x"], ops["x0"], None, algo.n,
+                                     d) is ops["x"]
+    with pytest.raises(ValueError):
+        harness.init_state_device(ops["x"], ops["x0"],
+                                  np.zeros((algo.n, d + 1)), algo.n, d)
+    r_pal = run_async_block_pallas(algo, bs=64, x_init=mid, max_iters=300,
+                                   sweeps_per_call=4)
+    r_jax = run_async_block(algo, bs=64, x_init=mid)
+    if algo.semiring.reduce == "sum":
+        np.testing.assert_allclose(r_pal.x, r_jax.x, atol=1e-4, rtol=1e-4)
+    else:
+        np.testing.assert_array_equal(r_pal.x, r_jax.x)
+
+
 def test_incremental_warm_start_through_pallas_backend():
     """run_incremental(engine='async_block', backend='pallas'): the warm
     state and the delta instance both ride the flat-BSR kernel path."""

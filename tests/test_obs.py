@@ -257,7 +257,9 @@ def test_spans_land_in_profiler_trace_nested(tmp_path):
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
 def test_solve_spans_nest(gw, backend):
     """``solve`` covers the relabel both ways; ``pack_build`` sits inside
-    ``pack``, and ``upload`` inside it on pallas, right after it on jax."""
+    ``pack``; on pallas one ``upload`` and one ``pack_device`` (the device
+    build's dispatch) follow it inside ``pack``, on jax ``upload`` comes
+    right after ``pack``."""
     algo = get_algorithm("sssp", gw, source=3)
     rank = np.random.default_rng(4).permutation(gw.n)
     tr = Tracer()
@@ -271,16 +273,23 @@ def test_solve_spans_nest(gw, backend):
     (sv,) = tr.find("solve")
     (pack,) = tr.find("pack")
     builds, ups = tr.find("pack_build"), tr.find("upload")
+    devs = tr.find("pack_device")
     relabels = tr.find("relabel")
     assert [r.attrs["direction"] for r in relabels] == ["forward", "inverse"]
     assert all(r.parent == sv.id for r in relabels)
     assert sv.parent is None and pack.parent == sv.id
     assert builds and all(b.parent == pack.id for b in builds)
-    # pallas alternates build and placement, operand by operand, so each
-    # transfer overlaps the host work on the operands after it
-    assert len(ups) == (5 if backend == "pallas" else 1)
-    assert all(u.parent == (pack.id if backend == "pallas" else sv.id)
-               and u.attrs["bytes"] > 0 for u in ups)
+    # pallas sends the compact skeleton in one transfer, then builds the
+    # tiles and the padded state on the device
+    (up,) = ups
+    assert up.parent == (pack.id if backend == "pallas" else sv.id)
+    assert up.attrs["bytes"] > 0
+    if backend == "pallas":
+        (dev,) = devs
+        assert dev.parent == pack.id
+        assert builds[-1].t_end <= up.t_start and up.t_end <= dev.t_start
+    else:
+        assert not devs
     assert relabels[0].t_end <= pack.t_start and pack.t_end <= relabels[1].t_start
 
 
