@@ -6,12 +6,12 @@ own comparison.
     python3 bench/control.py --workload <cell> --seeds 11 12 13 [--seconds 40]
 
 For each seed it runs the cell as ``bench/run.py`` does, with every answer
-the program produces replaced by the control's
-(``lib.reference.pagerank_control``): each solve's result, or each served
-query's answer as the server resolves it. It prints the run's numbers
-compared beside their limits; ``correct`` has to come out false. The
-benchmark's own runs never run it. Runs on the chip; without a TPU it exits
-non-zero.
+the program produces replaced by the control of the algorithm the cell's
+traffic names (``control`` in ``bench/algorithms/<algorithm>.py``): each
+solve's result, from that solve's root, or each served query's answer as
+the server resolves it. It prints the run's numbers compared beside their
+limits; ``correct`` has to come out false. The benchmark's own runs never
+run it. Runs on the chip; without a TPU it exits non-zero.
 """
 from __future__ import annotations
 
@@ -30,26 +30,34 @@ sys.path.insert(0, BENCH)
 
 @contextlib.contextmanager
 def in_place(cell, seed: int, seconds: float, graph_cache):
-    """Put the control in the program's place for one run of ``cell``."""
+    """Put the control in the program's place for one run of ``cell``: the
+    control of the algorithm its traffic names (``bench/algorithms/``),
+    in the program's float32."""
     import numpy as np
 
     import repro
     from lib import spec
     from lib.harness import load_graph
-    from lib.reference import pagerank_control
     from repro.serving.server import GraphServer
 
-    n, src, dst, w = load_graph(cell.config["graph"], seed, graph_cache)
-    damping = cell.traffic["params"]["damping"]
+    n, src, dst, w = load_graph(cell.config["graph"], graph_cache)
+    alg = spec.load_algorithm(cell.bench_dir, cell.traffic["algorithm"])
+    params = cell.traffic["params"]
     driver = cell.traffic["driver"]
+
+    def answers(roots):
+        return [np.asarray(x, np.float32)
+                for x in alg.control(n, src, dst, w, params, roots)]
+
     if driver == "closed_loop":
-        real, answer = repro.solve, []
+        real, control = repro.solve, {}
 
         def solve(algo, *a, **kw):
             res = real(algo, *a, **kw)
-            if not answer:
-                answer.append(pagerank_control(n, src, dst, w, damping).astype(np.float32))
-            res.x = answer[0].copy()
+            root = None if alg.ROOT_ARG is None else algo.params[alg.ROOT_ARG]
+            if root not in control:
+                (control[root],) = answers([root])
+            res.x = control[root].copy()
             return res
 
         with mock.patch.object(repro, "solve", solve):
@@ -61,8 +69,7 @@ def in_place(cell, seed: int, seconds: float, graph_cache):
         control = {}
         for lo in range(0, len(wanted), 32):
             part = wanted[lo:lo + 32]
-            x = pagerank_control(n, src, dst, w, damping, seeds=part).astype(np.float32)
-            control.update((v, x[:, c].copy()) for c, v in enumerate(part))
+            control.update(zip(part, answers(part)))
         real_resolve = GraphServer._resolve
 
         def _resolve(self, fam, j, t, converged):

@@ -20,8 +20,9 @@ The benchmark times every query itself, from when it was due to when the
 queries still in flight when the window closes are followed to resolution.
 End to end: ``query_p50_s``, ``query_p95_s`` over every query due in the
 window, and ``served_qps``, the queries resolved inside the window over its
-length. Checked: every answer to a query due in the window, by its float64
-residual (``lib.reference.residual_max``, ``resid_max`` in the limits file).
+length. Checked: every answer to a query due in the window, by the numbers
+of ``readings`` in ``bench/algorithms/<algorithm>.py`` (for ``ppr`` its
+float64 residual, ``resid_max`` in the limits file).
 """
 from __future__ import annotations
 
@@ -29,8 +30,8 @@ import time
 
 import numpy as np
 
+from lib import spec
 from lib.harness import Outcome, annotate
-from lib.reference import residual_max
 
 _GAP_STREAM = 20240719  # the fixed stream the arrival gaps are drawn from
 
@@ -104,6 +105,7 @@ def run(ctx) -> Outcome:
 
     tr = ctx.traffic
     algo_name, params = tr["algorithm"], dict(tr["params"])
+    alg = spec.load_algorithm(ctx.cell.bench_dir, algo_name)
     g, rank, (n, src, dst, w) = ctx.build_graph()
     srv = GraphServer(g, rank=rank, **ctx.config["server"])
     ctx.setup.mark("server")
@@ -147,8 +149,7 @@ def run(ctx) -> Outcome:
     answers = [t.result for t in tickets]
     del srv, tickets, sv
     t = time.perf_counter()
-    read = {"resid_max": residual_max(n, src, dst, w, params["damping"],
-                                      [int(v) for v in seeds], answers)}
+    read = alg.readings(n, src, dst, w, params, [int(v) for v in seeds], answers)
     ctx.log("reference", seconds=time.perf_counter() - t, checked=len(answers), **read)
     return Outcome(attempted=len(due), failed=failed, end_to_end=end_to_end,
                    checks={k: read[k] for k in ctx.cell.limits}, record=record)

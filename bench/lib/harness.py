@@ -76,18 +76,19 @@ class Context:
               flush=True)
 
     def build_graph(self):
-        """The configuration's graph from the seed and, where it asks for
-        one, the processing order the system computes for it. Returns
+        """The configuration's graph and, where it asks for one, the
+        processing order the system computes for it. Returns
         ``(graph, rank, arrays)`` where ``arrays`` is the benchmark's own
         ``(n, src, dst, w)`` for the reference.
 
-        The generated graph stands in for a dataset file: with a
-        ``graph_cache`` directory it is kept there per (graph section, seed)
-        and loaded in later runs. The order is the system's own work and is
-        computed in every run."""
+        The generated graph stands in for a dataset file, the same in every
+        run (``lib.generators.make_graph``): with a ``graph_cache``
+        directory it is kept there per graph section and loaded in later
+        runs. The order is the system's own work and is computed in every
+        run."""
         from repro.graphs.graph import Graph
 
-        n, src, dst, w = load_graph(self.config["graph"], self.seed, self.graph_cache)
+        n, src, dst, w = load_graph(self.config["graph"], self.graph_cache)
         g = Graph(n, src.copy(), dst.copy(), None if w is None else w.copy())
         self.setup.mark("graph")
         rank = None
@@ -141,8 +142,8 @@ class Context:
         self.device = device_record()
 
 
-def load_graph(graph: dict, seed: int, cache: str | None):
-    """``make_graph(graph, seed)``, kept in ``cache`` when one is given."""
+def load_graph(graph: dict, cache: str | None):
+    """``make_graph(graph)``, kept in ``cache`` when one is given."""
     import hashlib
     import json
 
@@ -151,14 +152,14 @@ def load_graph(graph: dict, seed: int, cache: str | None):
     from lib.generators import make_graph
 
     if cache is None:
-        return make_graph(graph, seed)
+        return make_graph(graph)
     key = hashlib.sha256(json.dumps(graph, sort_keys=True).encode()).hexdigest()[:16]
-    path = os.path.join(cache, f"{key}-{seed}.npz")
+    path = os.path.join(cache, f"{key}.npz")
     if os.path.exists(path):
         with np.load(path) as f:
             w = f["w"] if "w" in f else None
             return int(f["n"]), f["src"], f["dst"], w
-    n, src, dst, w = make_graph(graph, seed)
+    n, src, dst, w = make_graph(graph)
     os.makedirs(cache, exist_ok=True)
     tmp = f"{path}.{os.getpid()}.tmp.npz"
     arrays = {"n": np.int64(n), "src": src, "dst": dst}

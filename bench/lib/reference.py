@@ -1,4 +1,10 @@
-"""The plain reference the benchmark judges ``correct`` by, and its control.
+"""PageRank-family arithmetic of the plain reference, and its control.
+
+Each algorithm a traffic can name has its module in ``bench/algorithms/``
+(reference, control, numbers compared; ``pagerank.py`` says what each
+gives). The PageRank family's modules, ``pagerank.py`` and ``ppr.py``, call
+the functions here; the shortest-path ones (``sssp.py``, ``bfs.py``) stand
+alone. :func:`widest` serves every algorithm.
 
 PageRank and personalized PageRank as the configuration states them, written
 from their definitions and not from the program: the unnormalized PageRank

@@ -61,3 +61,14 @@ def load_module(path: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_algorithm(bench_dir: str, name: str):
+    """The module ``bench/algorithms/<name>.py`` of the algorithm a traffic
+    names: its float64 reference, its control one precision lower and the
+    numbers compared (see ``bench/algorithms/pagerank.py``)."""
+    path = os.path.join(bench_dir, "algorithms", name + ".py")
+    if not os.path.exists(path):
+        raise ValueError(f"no reference or control for algorithm {name!r}: "
+                         f"{path} is missing")
+    return load_module(path, "algorithm_" + name)

@@ -10,9 +10,12 @@ generator in ``bench/drivers/``), the limits its answers are held to
 (``bench/limits/<cell>.json``) and, in a traced run, one reader per
 per-layer metric (``bench/metrics/<metric>.py``).
 
-The run makes its graph and queries from ``--seed``, warms up every program
-the window drives (set-up), measures for ``--seconds``, then checks what the
-window produced against the plain reference in ``bench/lib/reference.py``.
+The run loads its configuration's graph (one dataset, drawn from a fixed
+seed by ``lib.generators.make_graph``), makes its queries and roots from
+``--seed``, warms up every program the window drives (set-up), measures
+for ``--seconds``, then checks what the window produced against the plain
+reference of the algorithm its traffic names
+(``bench/algorithms/<algorithm>.py``).
 Earlier lines report the set-up split, the compilations inside the window
 and every number compared beside its limit (also the last lines on standard
 error). The last line of standard output is one JSON object: ``correct``,

@@ -11,9 +11,15 @@ import pytest
 
 import repro
 from repro.serving.server import GraphServer
-from small import CELLS, run_small
+from small import CELLS, SHORTEST_PATH, run_small
 
-ANALYTICS = ["plc-pagerank", "grid-pagerank"]
+ANALYTICS = ["plc-pagerank", "grid-pagerank", "plc-sssp-small"]
+
+
+def window(cell: str) -> float:
+    """Seconds a test window lasts: long enough for a solve to complete in
+    it. An interpreted push solve takes about a second on a CPU."""
+    return 3.0 if cell in SHORTEST_PATH else 1.0
 
 
 def broken_solve(monkeypatch, fault):
@@ -58,7 +64,7 @@ def broken_server(monkeypatch, fault):
 @pytest.mark.parametrize("cell", ANALYTICS)
 def test_analytics_fault_is_not_correct(monkeypatch, cell, fault):
     broken_solve(monkeypatch, fault)
-    out = run_small(cell, seconds=1.0)
+    out = run_small(cell, seconds=window(cell))
     assert out["correct"] is False, out["checks"]
 
 
@@ -69,9 +75,9 @@ def test_serve_fault_is_not_correct(monkeypatch, fault):
     assert out["correct"] is False, out["checks"]
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + list(SHORTEST_PATH))
 def test_sound_run_is_correct(cell):
-    out = run_small(cell, seconds=1.0)
+    out = run_small(cell, seconds=window(cell))
     assert out["correct"] is True, out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 0
     assert list(out)[-1] == "checks"

@@ -76,6 +76,21 @@ def test_lattice_every_edge_both_ways():
     assert all(abs(s - d) in (1, 4) for s, d in arcs)
 
 
+def test_graph_is_drawn_from_its_sections_seed(tmp_path):
+    from lib.generators import GRAPH_SEED, make_graph
+    from lib.harness import load_graph
+    from small import small_cell
+
+    graph = small_cell("plc-pagerank").config["graph"]
+    assert "seed" not in graph
+    named = dict(graph, seed=GRAPH_SEED)
+    other = dict(graph, seed=GRAPH_SEED + 1)
+    a, b, c = make_graph(graph), load_graph(named, str(tmp_path)), load_graph(other, str(tmp_path))
+    assert all(np.array_equal(x, y) for x, y in zip(a[1:3], b[1:3]))
+    assert not (len(a[1]) == len(c[1]) and np.array_equal(a[1], c[1]))
+    assert len(list(tmp_path.iterdir())) == 2
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_graph_cache_gives_the_generated_graph(cell, tmp_path):
     from lib.generators import make_graph
@@ -83,9 +98,9 @@ def test_graph_cache_gives_the_generated_graph(cell, tmp_path):
     from small import small_cell
 
     graph = small_cell(cell).config["graph"]
-    made = make_graph(graph, 2**35 + 1)
-    first = load_graph(graph, 2**35 + 1, str(tmp_path))
-    again = load_graph(graph, 2**35 + 1, str(tmp_path))
+    made = make_graph(graph)
+    first = load_graph(graph, str(tmp_path))
+    again = load_graph(graph, str(tmp_path))
     assert len(list(tmp_path.iterdir())) == 1
     for a, b, c in zip(made, first, again):
         if a is None:
